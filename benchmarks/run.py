@@ -42,6 +42,7 @@ from benchmarks.common import (
 )
 from repro.core import GriffinConfig, evaluate
 from repro.core.flocking import flocking_score, pairwise_jaccard, sequence_statistic
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import decoder
 
 
@@ -534,20 +535,23 @@ def _kernel_error_vs_fp32_oracle(cfg, kvd: str) -> float:
         q = jnp.asarray(rng.normal(size=(B, S, H, hd)), jnp.float32)
         kn = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
         vn = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
-        pkf = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)),
+        pkf = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)),
                           jnp.float32)
-        pvf = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)),
+        pvf = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)),
                           jnp.float32)
         pos = jnp.asarray(rng.integers(page, page * 2, size=(B,)),
                           jnp.int32)
         ctx_f = ops.paged_attn_ref(q, kn, vn, pkf, pvf, bt, pos, wm)[0]
-        z = jnp.zeros((P + 1, page, KV, hd),
+        z = jnp.zeros((P + 1, KV, page, hd),
                       kv_quant.pool_jnp_dtype(kvd, cfg.dtype))
-        s0 = jnp.zeros((P + 1, 1, KV, 1), jnp.float32)
+        s0 = jnp.zeros((P + 1, KV, 1, 1), jnp.float32)
+        # page-major rows [(P+1)*page, KV, hd] in (page, offset) order
         pkq, sk = kv_quant.quantize_scatter_ref(
-            z, s0, gp, off, pkf.reshape(-1, KV, hd), kvd)
+            z, s0, gp, off, pkf.transpose(0, 2, 1, 3).reshape(-1, KV, hd),
+            kvd)
         pvq, sv = kv_quant.quantize_scatter_ref(
-            z, s0, gp, off, pvf.reshape(-1, KV, hd), kvd)
+            z, s0, gp, off, pvf.transpose(0, 2, 1, 3).reshape(-1, KV, hd),
+            kvd)
         ctx_q = ops.paged_attention(q, kn, vn, pkq, pvq, bt, pos, wm,
                                     scale_k=sk, scale_v=sv,
                                     kv_dtype=kvd)[0]
@@ -1001,6 +1005,9 @@ def bench_sharded(smoke: bool = False) -> None:
 
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # the child sets its own device count
+    # the child is a CPU emulation: it must never reach for an
+    # accelerator, which this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, str(Path(__file__).parent / "sharded_child.py")]
     if smoke:
         cmd.append("--smoke")
@@ -1503,6 +1510,7 @@ def main() -> None:
                     help="also write TRACE_<name>.json Chrome traces of "
                          "the serving benchmarks' drains (obs/trace.py)")
     args = ap.parse_args()
+    use_compile_cache()
     set_trace_dir(args.trace_dir)
     names = [n.strip() for n in (args.only.split(",") if args.only
                                  else list(BENCHES))]

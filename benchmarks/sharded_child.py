@@ -17,7 +17,7 @@ machine-readable JSON line the parent turns into ``BENCH_sharded.json``:
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # CPU emulation, even where a chip exists
 
 import argparse
 import json

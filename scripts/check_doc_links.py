@@ -7,10 +7,11 @@ Three checks over every ``*.md`` and ``*.py`` file in the repo:
 1. **Markdown links** ``[text](target)`` with a relative target must
    point at an existing file (resolved against the linking file's
    directory; ``#fragment`` stripped; external schemes skipped).
-2. **Doc-name mentions** — any all-caps ``*.md`` name (DESIGN.md,
+2. **Doc-name mentions** — any bare all-caps ``*.md`` name (DESIGN.md,
    EXPERIMENTS.md, ...) appearing anywhere must exist at the repo
    root.  This is what catches docstrings citing documentation that
-   was never written.
+   was never written.  A name behind a ``/`` is part of a path, to a
+   file elsewhere or in another project, and is not checked.
 3. **Section references** — ``DESIGN.md section 3`` / ``DESIGN.md #3``
    / ``EXPERIMENTS.md section Roofline`` must match a ``## ...``
    heading in the referenced file (numbered headings match on their
@@ -31,7 +32,7 @@ SCAN_DIRS = ("src", "tests", "benchmarks", "examples", "scripts")
 SKIP_PARTS = {".git", "__pycache__", "artifacts", ".venv", "node_modules"}
 
 MD_LINK = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
-DOC_NAME = re.compile(r"\b([A-Z][A-Z0-9_]*\.md)\b")
+DOC_NAME = re.compile(r"(?<!/)\b([A-Z][A-Z0-9_]*\.md)\b")
 # section refs are numbers ("section 3", "#3") or capitalized heading
 # words ("section Roofline") — lowercase words after "section" are prose
 SECTION_REF = re.compile(

@@ -43,7 +43,6 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shlib
@@ -116,7 +115,7 @@ class PagedTP:
                  backend: str = "gather", kv_dtype: str = "fp32"):
         self.cfg, self.mesh, self.axis, self.backend = cfg, mesh, axis, backend
         # quantized pools shard transparently: the scale pools carry the
-        # same ("pages", None, "kv_heads", None) axes as the data, so
+        # same ("pages", "kv_heads", None, None) axes as the data, so
         # each shard holds its heads' scales (per-shard scale bytes 1/N)
         # and the per-shard step runs the identical quantize program on
         # its local head slice
@@ -216,8 +215,8 @@ class PagedTP:
     # -- step functions ----------------------------------------------------
     def _wrap(self, fn, in_specs, out_specs, donate: Tuple[int, ...]):
         return jax.jit(
-            shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False),
+            jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
             donate_argnums=donate,
         )
 

@@ -3,8 +3,9 @@
 Every kernel wrapper in this package takes ``interpret: Optional[bool]``
 and resolves ``None`` through :func:`default_interpret` — compile to
 Mosaic on a real TPU backend, fall back to the Pallas interpreter
-everywhere else (this container is CPU-only; the kernels TARGET v5e and
-are validated against ``ref.py`` oracles in interpret mode).
+everywhere else (CPU tests validate the kernels against ``ref.py``
+oracles in interpret mode; ``tests/test_tpu_compile.py`` compiles them
+for a described v5e).
 
 Centralizing the choice here means no kernel can silently ship with a
 hardcoded ``interpret=True`` that would de-optimize real TPU runs — the

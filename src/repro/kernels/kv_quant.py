@@ -8,7 +8,7 @@ can hold their bytes in four dtypes, selected by ``kv_dtype``:
 * ``bf16`` — bfloat16 pages, no scales: the scatter rounds rows to
   bf16, attention upcasts to fp32.  Halves pool bytes.
 * ``int8`` — int8 pages + a parallel fp32 *scale pool* (one scale per
-  page per KV head, shape ``[P+1, 1, KV, 1]``), ~4x fewer pool bytes.
+  page per KV head, shape ``[P+1, KV, 1, 1]``), ~4x fewer pool bytes.
 * ``fp8``  — float8_e4m3fn pages + the same scale pool (gated on the
   installed jax exposing ``jnp.float8_e4m3fn``).
 
@@ -17,7 +17,7 @@ oracle ever see quantized bytes.**  The allocator, prefix cache, COW
 copies, pool donation, and TP sharding treat pages as opaque — the
 scale pool is just another pool leaf addressed by the same page ids,
 so ``decoder.copy_pool_pages``'s ``tree.map`` copies scales with their
-pages and the ``("pages", None, "kv_heads", None)`` axes shard scale
+pages and the ``("pages", "kv_heads", None, None)`` axes shard scale
 bytes 1/N alongside the data.
 
 The quantization program itself (identical float ops in the fused
@@ -119,8 +119,8 @@ def new_scale(s_old: jax.Array, amax_new: jax.Array, kv_dtype: str) -> jax.Array
 
 
 def quantize_scatter_ref(
-    pool: jax.Array,    # [P+1, page, KV, hd] quantized bits
-    scale: jax.Array,   # [P+1, 1, KV, 1] fp32
+    pool: jax.Array,    # [P+1, KV, page, hd] quantized bits
+    scale: jax.Array,   # [P+1, KV, 1, 1] fp32
     gp: jax.Array,      # [N] int32 destination page per new row
     offset: jax.Array,  # [N] int32 slot within the page
     rows: jax.Array,    # [N, KV, hd] new rows (any float dtype)
@@ -133,23 +133,24 @@ def quantize_scatter_ref(
     then writes the new rows.  Returns (new pool bits, new scales).
     """
     rows_f = rows.astype(jnp.float32)
-    P1, _, KV, _ = pool.shape
+    P1, KV = pool.shape[:2]
     amax = jnp.zeros((P1, KV), jnp.float32).at[gp].max(
         jnp.max(jnp.abs(rows_f), axis=-1)
     )
-    s_new = new_scale(scale[:, 0, :, 0], amax, kv_dtype)  # [P+1, KV]
+    s_new = new_scale(scale[:, :, 0, 0], amax, kv_dtype)  # [P+1, KV]
     s_eff = jnp.maximum(s_new, EPS)
     old_f = dequantize(pool, scale)
-    requant = quantize(old_f, s_eff[:, None, :, None], kv_dtype)
+    requant = quantize(old_f, s_eff[:, :, None, None], kv_dtype)
     new_bits = quantize(rows_f, s_eff[gp][:, :, None], kv_dtype)
-    return requant.at[gp, offset].set(new_bits), s_new[:, None, :, None]
+    return (requant.at[gp, :, offset].set(new_bits),
+            s_new[:, :, None, None])
 
 
 def gather_scales(scale: jax.Array, block_tables: jax.Array,
                   page_size: int) -> jax.Array:
-    """[P+1, 1, KV, 1] scales -> [B, n*page, KV, 1] aligned with the
+    """[P+1, KV, 1, 1] scales -> [B, n*page, KV, 1] aligned with the
     gathered page view (one scale repeated across a page's slots)."""
-    s = jnp.take(scale[:, 0, :, 0], jnp.clip(block_tables, 0), axis=0)
+    s = jnp.take(scale[:, :, 0, 0], jnp.clip(block_tables, 0), axis=0)
     return jnp.repeat(s, page_size, axis=1)[..., None]
 
 

@@ -3,8 +3,7 @@
 Backend selection is centralized in ``kernels/backend.py``: every
 kernel takes ``interpret=None`` and resolves it through
 ``default_interpret()`` — compile to Mosaic on TPU, interpret
-everywhere else (this container is CPU-only; the kernels TARGET v5e
-and are validated against ``ref.py`` per the assignment).
+everywhere else (the CPU tests validate them against ``ref.py``).
 """
 from __future__ import annotations
 
@@ -42,19 +41,20 @@ def glu_ffn_forward(x, wg, w1, w2, *, activation: str = "swiglu"):
 
 
 def paged_gather(pool, block_tables):
-    """Block-table page gather. pool [P, page, E]; bt [B, n] -> [B, n, page, E]."""
+    """Block-table page gather. pool [P, ...]; bt [B, n] -> [B, n, ...]."""
     return _paged_gather(pool, jnp.clip(block_tables, 0))
 
 
 def paged_kv_gather(pool, block_tables):
-    """KV-shaped wrapper: pool [P, page, KV, hd] -> [B, n*page, KV, hd].
+    """KV-shaped wrapper: pool [P, KV, page, hd] -> [B, n*page, KV, hd].
 
-    Flattens the (KV, hd) tail to one lane-aligned axis for the kernel.
+    The kernel copies whole pages (every head's [page, hd] tile); the
+    token-major view is one transpose of the gathered pages.
     """
-    P, page, KV, hd = pool.shape
+    _, KV, page, hd = pool.shape
     B, n = block_tables.shape
-    out = paged_gather(pool.reshape(P, page, KV * hd), block_tables)
-    return out.reshape(B, n * page, KV, hd)
+    out = paged_gather(pool, block_tables)  # [B, n, KV, page, hd]
+    return out.transpose(0, 1, 3, 2, 4).reshape(B, n * page, KV, hd)
 
 
 def paged_attention(q, k_new, v_new, pool_k, pool_v, block_tables, pos,

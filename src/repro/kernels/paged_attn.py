@@ -71,6 +71,13 @@ from repro.kernels.backend import resolve_interpret
 NEG_INF = -2.0e38  # large finite negative (matches attention.py)
 
 
+def _absmax11(x: jax.Array) -> jax.Array:
+    """max |x| over a 2-D block as a [1, 1] vector (max is exact in any
+    order, so this equals the oracle's flat ``jnp.max``)."""
+    a = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    return jnp.max(a, axis=0, keepdims=True)
+
+
 def _kernel(
     # scalar prefetch
     bt_ref,    # [B, W] int32 page ids (-1 = unallocated)
@@ -81,14 +88,14 @@ def _kernel(
     #   q_ref   [1, 1, S*G, hd] queries of (b, kv)
     #   kn_ref  [1, 1, S, hd] new keys of (b, kv)
     #   vn_ref  [1, 1, S, hd] new values of (b, kv)
-    #   wm_ref  [1, S] int32 write mask of b
-    #   pk_ref  [1, page, 1, hd] key page (pre-scatter bits)
-    #   pv_ref  [1, page, 1, hd] value page
+    #   wm_ref  [1, 1, S] int32 write mask of b
+    #   pk_ref  [1, 1, page, hd] key page of head kv (pre-scatter bits)
+    #   pv_ref  [1, 1, page, hd] value page of head kv
     #   sk_ref  [1, 1, 1, 1] fp32 key-page scale        (quantized only)
     #   sv_ref  [1, 1, 1, 1] fp32 value-page scale      (quantized only)
     #   ctx_ref [1, 1, S*G, hd] fp32 attention output of (b, kv)
-    #   opk_ref [1, page, 1, hd] updated key page (aliases pk)
-    #   opv_ref [1, page, 1, hd] updated value page (aliases pv)
+    #   opk_ref [1, 1, page, hd] updated key page (aliases pk)
+    #   opv_ref [1, 1, page, hd] updated value page (aliases pv)
     #   osk_ref [1, 1, 1, 1] updated key scale (aliases sk, quantized)
     #   osv_ref [1, 1, 1, 1] updated value scale (aliases sv, quantized)
     #   m_ref   [S*G, 128] fp32 running row max
@@ -135,15 +142,15 @@ def _kernel(
     # slot's absolute position equals pos+s and s is really written —
     # at most one s matches per slot, so the contraction reproduces the
     # row bits exactly (a single 1.0 multiply)
-    k_page = pk_ref[0, :, 0, :]  # [page, hd]
-    v_page = pv_ref[0, :, 0, :]
+    k_page = pk_ref[0, 0]  # [page, hd]
+    v_page = pv_ref[0, 0]
     kpos_col = j_eff * page + jax.lax.broadcasted_iota(
         jnp.int32, (page, 1), 0
     )  # [page, 1] absolute position of each slot
     new_pos = posb + jax.lax.broadcasted_iota(jnp.int32, (page, S), 1)
-    onehot = (kpos_col == new_pos) & (wm_ref[0, :][None, :] > 0)
-    hit = jnp.any(onehot, axis=1, keepdims=True)  # [page, 1]
+    onehot = (kpos_col == new_pos) & (wm_ref[0] > 0)
     oh = onehot.astype(jnp.float32)
+    hit = jnp.max(oh, axis=1, keepdims=True) > 0  # [page, 1]
     k_scat = jax.lax.dot_general(
         oh, kn_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -159,21 +166,20 @@ def _kernel(
         # then re-encode the whole page under it.  No new rows ->
         # s_new == s_old and the re-encode restores the old bits
         # exactly, so the unconditional write-back stays benign for
-        # shared (read-only) pages and clamped tail steps.
-        sk_old = sk_ref[0, 0, 0, 0]
-        sv_old = sv_ref[0, 0, 0, 0]
-        sk_new = kv_quant.new_scale(
-            sk_old, jnp.max(jnp.abs(k_scat)), kv_dtype)
-        sv_new = kv_quant.new_scale(
-            sv_old, jnp.max(jnp.abs(v_scat)), kv_dtype)
+        # shared (read-only) pages and clamped tail steps.  Scales stay
+        # [1, 1] vectors: VMEM holds no scalars.
+        sk_old = sk_ref[0, 0]
+        sv_old = sv_ref[0, 0]
+        sk_new = kv_quant.new_scale(sk_old, _absmax11(k_scat), kv_dtype)
+        sv_new = kv_quant.new_scale(sv_old, _absmax11(v_scat), kv_dtype)
         sk_eff = jnp.maximum(sk_new, kv_quant.EPS)
         sv_eff = jnp.maximum(sv_new, kv_quant.EPS)
         k_f = jnp.where(hit, k_scat, k_page.astype(jnp.float32) * sk_old)
         v_f = jnp.where(hit, v_scat, v_page.astype(jnp.float32) * sv_old)
         k_page = kv_quant.quantize(k_f, sk_eff, kv_dtype)
         v_page = kv_quant.quantize(v_f, sv_eff, kv_dtype)
-        osk_ref[0, 0, 0, 0] = sk_new
-        osv_ref[0, 0, 0, 0] = sv_new
+        osk_ref[0, 0] = sk_new
+        osv_ref[0, 0] = sv_new
         k_att = k_page.astype(jnp.float32) * sk_new
         v_att = v_page.astype(jnp.float32) * sv_new
     else:
@@ -183,8 +189,8 @@ def _kernel(
         # pools round on write, upcast on read)
         k_att = k_page.astype(jnp.float32)
         v_att = v_page.astype(jnp.float32)
-    opk_ref[0, :, 0, :] = k_page
-    opv_ref[0, :, 0, :] = v_page
+    opk_ref[0, 0] = k_page
+    opv_ref[0, 0] = v_page
 
     # -- online-softmax accumulation over owned pages ---------------------
     attend = j < npb
@@ -236,13 +242,13 @@ def paged_attn(
     q: jax.Array,            # [B, S, H, hd] (rope applied)
     k_new: jax.Array,        # [B, S, KV, hd]
     v_new: jax.Array,        # [B, S, KV, hd]
-    pool_k: jax.Array,       # [P+1, page, KV, hd]
-    pool_v: jax.Array,       # [P+1, page, KV, hd]
+    pool_k: jax.Array,       # [P+1, KV, page, hd]
+    pool_v: jax.Array,       # [P+1, KV, page, hd]
     block_tables: jax.Array, # [B, W] int32 page ids, -1 = unallocated
     pos: jax.Array,          # [B] int32 tokens already cached
     write_mask: jax.Array,   # [B, S] bool
     *,
-    scale_k: Optional[jax.Array] = None,  # [P+1, 1, KV, 1] fp32
+    scale_k: Optional[jax.Array] = None,  # [P+1, KV, 1, 1] fp32
     scale_v: Optional[jax.Array] = None,
     kv_dtype: str = "fp32",
     window: int = 0,
@@ -258,7 +264,7 @@ def paged_attn(
     assert H % KV == 0, (H, KV)
     G = H // KV
     SG = S * G
-    page = pool_k.shape[1]
+    page = pool_k.shape[2]
     W = block_tables.shape[1]
     scale = 1.0 / math.sqrt(hd)
     quantized = kv_quant.is_quantized(kv_dtype)
@@ -276,7 +282,7 @@ def paged_attn(
     num_pages = jnp.minimum(
         (pos.astype(jnp.int32) + S + page - 1) // page, n_alloc
     )
-    wm = write_mask.astype(jnp.int32)
+    wm = write_mask.astype(jnp.int32)[:, None, :]  # [B, 1, S]
     trash = pool_k.shape[0] - 1
 
     def page_idx(b, kv, j, bt, pos, np_):
@@ -286,7 +292,7 @@ def paged_attn(
         # when the unconditional block write-back flushes stale bits)
         last = jnp.maximum(jnp.minimum(j, np_[b] - 1), 0)
         p = bt[b, last]
-        return (jnp.where(p < 0, trash, p), 0, kv, 0)
+        return (jnp.where(p < 0, trash, p), kv, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, SG, hd),
@@ -295,15 +301,15 @@ def paged_attn(
                      lambda b, kv, j, *_: (b, kv, 0, 0)),
         pl.BlockSpec((1, 1, S, hd),
                      lambda b, kv, j, *_: (b, kv, 0, 0)),
-        pl.BlockSpec((1, S), lambda b, kv, j, *_: (b, 0)),
-        pl.BlockSpec((1, page, 1, hd), page_idx),
-        pl.BlockSpec((1, page, 1, hd), page_idx),
+        pl.BlockSpec((1, 1, S), lambda b, kv, j, *_: (b, 0, 0)),
+        pl.BlockSpec((1, 1, page, hd), page_idx),
+        pl.BlockSpec((1, 1, page, hd), page_idx),
     ]
     out_specs = [
         pl.BlockSpec((1, 1, SG, hd),
                      lambda b, kv, j, *_: (b, kv, 0, 0)),
-        pl.BlockSpec((1, page, 1, hd), page_idx),
-        pl.BlockSpec((1, page, 1, hd), page_idx),
+        pl.BlockSpec((1, 1, page, hd), page_idx),
+        pl.BlockSpec((1, 1, page, hd), page_idx),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, KV, SG, hd), jnp.float32),

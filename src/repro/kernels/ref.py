@@ -54,8 +54,8 @@ def glu_ffn_ref(x: jax.Array, wg: jax.Array, w1: jax.Array, w2: jax.Array,
 
 
 def paged_gather_ref(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Block-table page gather. pool [P, page, E]; block_tables [B, n]
-    int32 (entries pre-clipped to >= 0) -> [B, n, page, E]."""
+    """Block-table page gather. pool [P, ...]; block_tables [B, n]
+    int32 (entries pre-clipped to >= 0) -> [B, n, ...]."""
     return jnp.take(pool, jnp.clip(block_tables, 0), axis=0)
 
 
@@ -63,14 +63,14 @@ def paged_attn_ref(
     q: jax.Array,            # [B, S, H, hd]
     k_new: jax.Array,        # [B, S, KV, hd]
     v_new: jax.Array,        # [B, S, KV, hd]
-    pool_k: jax.Array,       # [P+1, page, KV, hd]
-    pool_v: jax.Array,       # [P+1, page, KV, hd]
+    pool_k: jax.Array,       # [P+1, KV, page, hd]
+    pool_v: jax.Array,       # [P+1, KV, page, hd]
     block_tables: jax.Array, # [B, W] int32, -1 = unallocated
     pos: jax.Array,          # [B] int32
     write_mask: jax.Array,   # [B, S] bool
     window: int = 0,
     *,
-    scale_k: jax.Array = None,  # [P+1, 1, KV, 1] fp32 (quantized pools)
+    scale_k: jax.Array = None,  # [P+1, KV, 1, 1] fp32 (quantized pools)
     scale_v: jax.Array = None,
     kv_dtype: str = "fp32",
 ):
@@ -94,7 +94,7 @@ def paged_attn_ref(
     B, S, H, hd = q.shape
     KV = k_new.shape[2]
     G = H // KV
-    page = pool_k.shape[1]
+    page = pool_k.shape[2]
     trash = pool_k.shape[0] - 1
     W = block_tables.shape[1]
     quantized = kv_quant.is_quantized(kv_dtype)
@@ -115,20 +115,19 @@ def paged_attn_ref(
             pool_v, scale_v, gpf, off, v_new.reshape(B * S, KV, hd), kv_dtype
         )
     else:
-        new_k = pool_k.at[gpf, off].set(
+        new_k = pool_k.at[gpf, :, off].set(
             k_new.reshape(B * S, KV, hd).astype(pool_k.dtype)
         )
-        new_v = pool_v.at[gpf, off].set(
+        new_v = pool_v.at[gpf, :, off].set(
             v_new.reshape(B * S, KV, hd).astype(pool_v.dtype)
         )
-    k_cache = paged_gather_ref(
-        new_k.reshape(pool_k.shape[0], page, KV * hd),
-        block_tables,
-    ).reshape(B, W * page, KV, hd)
-    v_cache = paged_gather_ref(
-        new_v.reshape(pool_v.shape[0], page, KV * hd),
-        block_tables,
-    ).reshape(B, W * page, KV, hd)
+
+    def view(pool):  # [B, W, KV, page, hd] -> [B, W*page, KV, hd]
+        g = paged_gather_ref(pool, block_tables).transpose(0, 1, 3, 2, 4)
+        return g.reshape(B, W * page, KV, hd)
+
+    k_cache = view(new_k)
+    v_cache = view(new_v)
     if quantized:
         k_cache = kv_quant.dequantize(
             k_cache, kv_quant.gather_scales(new_sk, block_tables, page)

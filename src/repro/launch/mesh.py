@@ -6,6 +6,16 @@ jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    (``distributed/sharding.py``) place arrays with ``NamedSharding``s
+    and ``with_sharding_constraint`` and leave propagation to GSPMD,
+    which ``Explicit`` axes (the ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,12 +36,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             "launch via repro.launch.dryrun (it sets "
             "--xla_force_host_platform_device_count=512 before jax init)"
         )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return auto_mesh(shape, axes, devices)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over host CPU devices (tests)."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_serving_mesh(n: int, axis: str = "model"):
@@ -49,4 +59,4 @@ def make_serving_mesh(n: int, axis: str = "model"):
             "on CPU set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={n} before starting the process"
         )
-    return jax.make_mesh((n,), (axis,), devices=devices[:n])
+    return auto_mesh((n,), (axis,), devices[:n])

@@ -17,13 +17,17 @@ to the single-device path.  On CPU, emulate devices first:
   PYTHONPATH=src python -m repro.launch.serve --arch tinylm-tp \
       --mesh model=2 --requests 8
 
-On this CPU container it serves the framework-trained tiny model (or an
-untrained smoke config for other archs); on a real pod the same engine
-runs under the production mesh policies (see repro/launch/cells.py for
-the sharded step construction the dry-run exercises).
+``tinylm``/``tinylm-tp`` serve the framework-trained tiny model; every
+other arch serves its published config with bf16 weights from a seed.
+``--layers N`` cuts the depth only (widths stay published); ``--smoke``
+swaps in the reduced same-family config for CPU runs:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --layers 8 \
+      --kernel-backend fused --tier 0.5
 """
 import argparse
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
@@ -32,6 +36,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import ASSIGNED_ARCHS, get_config
 from repro.core import TIERS, GriffinConfig, SparsityProfile
 from repro.data.pipeline import SyntheticCorpus
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import decoder
 from repro.serving.engine import ContinuousBatcher
@@ -51,11 +56,39 @@ def parse_mesh(spec: str):
         )
 
 
-def main() -> None:
+def parse_range(spec: str):
+    """``LO:HI`` -> (LO, HI) inclusive token counts."""
+    try:
+        lo, hi = (int(v) for v in spec.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"want LO:HI, got {spec!r}")
+    if not 0 < lo <= hi:
+        raise argparse.ArgumentTypeError(f"want 0 < LO <= HI, got {spec!r}")
+    return lo, hi
+
+
+def main(argv: Optional[List[str]] = None) -> Optional[dict]:
+    """Serve synthetic requests (or HTTP).  Returns, for a drained
+    synthetic run on the paged path, ``{"cfg", "requests" [(prompt,
+    max_new)], "results" {rid: tokens}, "seconds", "server"}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinylm",
                     choices=ASSIGNED_ARCHS + ["tinylm", "tinylm-tp"])
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="cut the depth to N layers (widths stay as "
+                         "published)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family config (tiny "
+                         "widths, for CPU runs) instead of the published "
+                         "one")
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--prompt-len", type=parse_range, default=None,
+                    metavar="LO:HI",
+                    help="synthetic prompt lengths, uniform in [LO, HI] "
+                         "(default 16:max_len/2-1)")
+    ap.add_argument("--max-new", type=int, default=None, metavar="N",
+                    help="tokens generated per synthetic request "
+                         "(default uniform in [8, 32))")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
@@ -120,6 +153,11 @@ def main() -> None:
                          "fused attention shard along KV heads, GRIFFIN "
                          "experts along the FF hidden axis; output is "
                          "token-identical to single-device serving")
+    ap.add_argument("--tp-shards", type=int, default=1, metavar="N",
+                    help="balance the GRIFFIN expert selection over N "
+                         "tensor-parallel shards, as --mesh model=N does; "
+                         "on one device this reproduces an N-way sharded "
+                         "run's outputs")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="record the drain as structured spans and write "
@@ -149,9 +187,13 @@ def main() -> None:
                     help="default SLO class for requests that do not "
                          "name one (priority + TTFT deadline; expired "
                          "requests that produced nothing are shed)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.arch in ("tinylm", "tinylm-tp"):
+        if args.layers is not None or args.smoke:
+            ap.error(f"{args.arch} serves its trained checkpoint as is; "
+                     f"--layers/--smoke apply to published archs")
         cfg = get_config(args.arch)
         ckpt_dir = args.ckpt_dir or f"artifacts/models/{args.arch}-s500"
         mgr = CheckpointManager(ckpt_dir, interval=1)
@@ -165,7 +207,9 @@ def main() -> None:
                   f"UNTRAINED init (train one via benchmarks.common."
                   f"trained_tiny or pass --ckpt-dir)")
     else:
-        cfg = get_config(args.arch, smoke=True)
+        cfg = get_config(args.arch, smoke=args.smoke)
+        if args.layers is not None:
+            cfg = cfg.replace(num_layers=args.layers)
         params = decoder.init_params(cfg, jax.random.PRNGKey(0))
 
     # per_shard_topk inherits the single-sourced default
@@ -173,6 +217,8 @@ def main() -> None:
     # server forces it on under a mesh either way
     gcfg = None if (args.no_griffin or not cfg.griffin or not cfg.has_ffn) \
         else GriffinConfig(sparsity=args.sparsity)
+    if gcfg is not None and args.tp_shards > 1:
+        gcfg = gcfg.replace(tp_shards=args.tp_shards, per_shard_topk=True)
     profile = None
     if args.sparsity_profile is not None:
         if args.tier is None:
@@ -186,9 +232,10 @@ def main() -> None:
         ap.error("--tier requires GRIFFIN (drop --no-griffin)")
     corpus = SyntheticCorpus(vocab=cfg.vocab_size, seed=0)
     rng = np.random.default_rng(0)
+    lo, hi = args.prompt_len or (16, args.max_len // 2 - 1)
     reqs = [
-        (corpus.sample(int(rng.integers(16, args.max_len // 2)), seed=500 + rid),
-         int(rng.integers(8, 32)))
+        (corpus.sample(int(rng.integers(lo, hi + 1)), seed=500 + rid),
+         args.max_new or int(rng.integers(8, 32)))
         for rid in range(args.requests)
     ]
 
@@ -317,7 +364,8 @@ def main() -> None:
         if args.metrics_snapshot:
             path = srv.metrics.write_snapshot(args.metrics_snapshot)
             print(f"[obs] metrics snapshot -> {path}")
-        return
+        return {"cfg": cfg, "requests": reqs, "results": results,
+                "seconds": dt, "server": srv}
 
     cb = ContinuousBatcher(cfg, params, n_slots=args.slots,
                            max_len=args.max_len, gcfg=gcfg)

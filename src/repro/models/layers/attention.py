@@ -291,27 +291,30 @@ def paged_cache_specs(cfg, num_pages: int, page_size: int,
 
     kv_quant.resolve_kv_dtype(kv_dtype)
     KV, hd = cfg.num_kv_heads, cfg.head_dim
-    axes = ("pages", "page", "kv_heads", "head_dim")
+    # KV-head-major: one head's page is a contiguous [page, hd] tile,
+    # which is the block the fused kernel DMAs (Mosaic wants the last
+    # two block dims whole or (8, 128)-aligned)
+    axes = ("pages", "kv_heads", "page", "head_dim")
     dt = None if kv_dtype == "fp32" else str(
         kv_quant.pool_jnp_dtype(kv_dtype, cfg.dtype)
     )
     specs = {
-        "k": ParamSpec((num_pages + 1, page_size, KV, hd), axes,
+        "k": ParamSpec((num_pages + 1, KV, page_size, hd), axes,
                        init="zeros", dtype=dt),
-        "v": ParamSpec((num_pages + 1, page_size, KV, hd), axes,
+        "v": ParamSpec((num_pages + 1, KV, page_size, hd), axes,
                        init="zeros", dtype=dt),
     }
     if kv_quant.is_quantized(kv_dtype):
-        s_axes = ("pages", None, "kv_heads", None)
-        specs["k_scale"] = ParamSpec((num_pages + 1, 1, KV, 1), s_axes,
+        s_axes = ("pages", "kv_heads", None, None)
+        specs["k_scale"] = ParamSpec((num_pages + 1, KV, 1, 1), s_axes,
                                      init="zeros", dtype="float32")
-        specs["v_scale"] = ParamSpec((num_pages + 1, 1, KV, 1), s_axes,
+        specs["v_scale"] = ParamSpec((num_pages + 1, KV, 1, 1), s_axes,
                                      init="zeros", dtype="float32")
     return specs
 
 
 def _gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """pool [P+1, page, KV, hd], block_tables [B, n] -> [B, n*page, KV, hd].
+    """pool [P+1, KV, page, hd], block_tables [B, n] -> [B, n*page, KV, hd].
 
     On TPU this is the Pallas paged-gather kernel (scalar-prefetched
     block ids drive the BlockSpec index map); off-TPU a plain take.
@@ -321,8 +324,9 @@ def _gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
 
         return ops.paged_kv_gather(pool, block_tables)
     B, n = block_tables.shape
-    g = jnp.take(pool, jnp.clip(block_tables, 0), axis=0)  # [B, n, page, KV, hd]
-    return g.reshape(B, n * pool.shape[1], *pool.shape[2:])
+    _, KV, page, hd = pool.shape
+    g = jnp.take(pool, jnp.clip(block_tables, 0), axis=0)  # [B, n, KV, page, hd]
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, n * page, KV, hd)
 
 
 def resolve_attn_backend(backend: str) -> str:
@@ -384,7 +388,7 @@ def paged_attn_step(
     from repro.kernels import kv_quant
 
     B, S, D = x.shape
-    page = pool["k"].shape[1]
+    page = pool["k"].shape[2]
     trash = pool["k"].shape[0] - 1
     quantized = kv_quant.is_quantized(kv_dtype)
     if quantized:
@@ -432,10 +436,10 @@ def paged_attn_step(
         new_pool = {"k": nk, "v": nv, "k_scale": nsk, "v_scale": nsv}
     else:
         new_pool = {
-            "k": pool["k"].at[gpf, off].set(
+            "k": pool["k"].at[gpf, :, off].set(
                 k_new.reshape(B * S, KV, hd).astype(pool["k"].dtype)
             ),
-            "v": pool["v"].at[gpf, off].set(
+            "v": pool["v"].at[gpf, :, off].set(
                 v_new.reshape(B * S, KV, hd).astype(pool["v"].dtype)
             ),
         }

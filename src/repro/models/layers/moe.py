@@ -181,7 +181,6 @@ def _dispatch_combine_local(params_loc, x, gate, idx, cfg, e0: int, e_loc: int,
 def _moe_routed_ep(params, xt, gate, idx, cfg, mesh, n_model):
     """shard_map EP: experts sharded over ``model``; tokens data-sharded
     and replicated over ``model``; combine = psum over ``model``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e_loc = cfg.num_experts // n_model
@@ -199,12 +198,12 @@ def _moe_routed_ep(params, xt, gate, idx, cfg, mesh, n_model):
         )
         return jax.lax.psum(y, "model")
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(w_spec, tok_spec, tok_spec, tok_spec),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(expert_params, xt, gate, idx)
 
 
@@ -236,7 +235,6 @@ def _moe_grouped_ep2d(params, xt, cfg, mesh, nd, nm):
     ever crosses the ``data`` axis, and the only collective is the
     per-row combine psum over ``model``.  Returns (y, aux).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     E = cfg.num_experts
@@ -270,12 +268,12 @@ def _moe_grouped_ep2d(params, xt, cfg, mesh, nd, nm):
         y = jax.lax.psum(y, "model")
         return y, jax.lax.pmean(aux, "model")
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(router_spec, w_spec, tok_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], expert_params, xt)
     return y, jnp.mean(aux)
 

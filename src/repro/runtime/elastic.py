@@ -16,6 +16,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.distributed import sharding as shlib
+from repro.launch.mesh import auto_mesh
 
 
 @dataclass(frozen=True)
@@ -51,7 +52,7 @@ def build_mesh(plan: RemeshPlan, devices=None) -> Mesh:
     for s in plan.new_shape:
         n *= s
     devices = (devices if devices is not None else jax.devices())[:n]
-    return jax.make_mesh(plan.new_shape, plan.axes, devices=devices)
+    return auto_mesh(plan.new_shape, plan.axes, devices)
 
 
 def reshard(tree: Any, spec_tree: Any, mesh: Mesh, rules) -> Any:

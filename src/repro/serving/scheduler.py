@@ -410,6 +410,7 @@ class Scheduler:
         req.state = FINISHED
         req.aborted = True
         req.slot = None
+        req.pruned_host = None
         self.finished[req.rid] = req
         if self.spec_ctl is not None:
             self.spec_ctl.forget(req.rid)
@@ -632,6 +633,9 @@ class Scheduler:
         req.table = BlockTable()
         req.state = FINISHED
         req.slot = None
+        # a finished request keeps its tokens, not its compacted FF
+        # weights (device arrays as large as a layer's FF per request)
+        req.pruned_host = None
         self.finished[req.rid] = req
         if self.spec_ctl is not None:
             self.spec_ctl.forget(req.rid)

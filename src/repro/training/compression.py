@@ -14,7 +14,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantize_leaf(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -62,12 +61,12 @@ def make_compressed_allreduce(mesh: Mesh, axis_name: str = "data"):
         spec = P(axis_name)  # grads replicated per shard on other axes
         # operate leaf-wise fully replicated within the axis: grads enter
         # replicated; treat them as per-device values to be averaged
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(grads, residual)
 
     return fn

@@ -12,11 +12,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import auto_mesh
 from repro.training.compression import init_residual, make_compressed_allreduce
 
 assert jax.device_count() == 8
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 allreduce = make_compressed_allreduce(mesh, "data")
 
 rng = np.random.default_rng(0)

@@ -15,6 +15,7 @@ from repro.checkpoint import checkpointer
 from repro.configs.registry import get_config
 from repro.distributed import sharding as shlib
 from repro.models import decoder
+from repro.launch.mesh import auto_mesh
 from repro.runtime.elastic import build_mesh, plan_remesh, reshard
 from repro.training import optimizer as opt_lib
 from repro.training.train_step import build_train_step, init_train_state
@@ -28,7 +29,7 @@ cfg = get_config("tinylm").replace(
 opt = opt_lib.adamw(1e-2)
 rules = shlib.make_rules(phase="train", fsdp=False)
 
-mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+mesh1 = auto_mesh((4, 2), ("data", "model"))
 state = init_train_state(cfg, opt, jax.random.PRNGKey(0))
 
 def make_step(mesh):

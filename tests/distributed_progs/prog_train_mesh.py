@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.distributed import sharding as shlib
+from repro.launch.mesh import auto_mesh
 from repro.models import decoder
 from repro.training import optimizer as opt_lib
 from repro.training.train_step import build_train_step, init_train_state
@@ -23,7 +24,7 @@ cfg = get_config("yi-9b", smoke=True).replace(
     d_model=64, d_ff=128, num_heads=4, num_kv_heads=2, vocab_size=256,
     dtype="float32",
 )
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 rules = shlib.make_rules(phase="train", fsdp=True)
 # 4-way model axis needs dims % 4 == 0: d_ff 128 ok, heads 4 ok, vocab 256 ok
 

@@ -26,6 +26,7 @@ def test_checker_detects_breakage(tmp_path):
     (tmp_path / design).write_text("# D\n\n## 1. Only section\n")
     (tmp_path / ("bad".upper() + ".md")).write_text(
         f"[x](missing.md)\nsee {ghost}\n{design} section 99\n"
+        f"a path, not a root doc: other/{ghost}\n"
     )
     mod.REPO = tmp_path
     errors = mod.check()

@@ -48,17 +48,19 @@ def _mk_quant_case(rng, B, S, H, KV, hd, page, W, kvd):
     lens = rng.integers(0, (W - 1) * page - S, size=B)
     need = [-(-(int(l) + S) // page) for l in lens]
     P = sum(need) + 2
-    pkf = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)), jnp.float32)
-    pvf = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)), jnp.float32)
-    z = jnp.zeros((P + 1, page, KV, hd),
+    pkf = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)), jnp.float32)
+    pvf = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)), jnp.float32)
+    z = jnp.zeros((P + 1, KV, page, hd),
                   kv_quant.pool_jnp_dtype(kvd, "float32"))
-    s0 = jnp.zeros((P + 1, 1, KV, 1), jnp.float32)
+    s0 = jnp.zeros((P + 1, KV, 1, 1), jnp.float32)
     gp = jnp.arange(P + 1).repeat(page)
     off = jnp.tile(jnp.arange(page), P + 1)
-    pk, sk = kv_quant.quantize_scatter_ref(
-        z, s0, gp, off, pkf.reshape(-1, KV, hd), kvd)
-    pv, sv = kv_quant.quantize_scatter_ref(
-        z, s0, gp, off, pvf.reshape(-1, KV, hd), kvd)
+
+    def rows(pool):  # [P+1, KV, page, hd] -> [(P+1)*page, KV, hd]
+        return pool.transpose(0, 2, 1, 3).reshape(-1, KV, hd)
+
+    pk, sk = kv_quant.quantize_scatter_ref(z, s0, gp, off, rows(pkf), kvd)
+    pv, sv = kv_quant.quantize_scatter_ref(z, s0, gp, off, rows(pvf), kvd)
     bt = np.full((B, W), -1, np.int32)
     perm = rng.permutation(P)
     c = 0
@@ -138,9 +140,9 @@ def test_monotone_scale_and_old_rows_stay_decodable(kvd):
     the *new* scale after growing data re-encodes the page."""
     rng = np.random.default_rng(19)
     KV, hd, page, P = 2, 8, 8, 4
-    z = jnp.zeros((P + 1, page, KV, hd),
+    z = jnp.zeros((P + 1, KV, page, hd),
                   kv_quant.pool_jnp_dtype(kvd, "float32"))
-    s = jnp.zeros((P + 1, 1, KV, 1), jnp.float32)
+    s = jnp.zeros((P + 1, KV, 1, 1), jnp.float32)
     pool = z
     written = {}  # (page, slot) -> fp32 row
     prev_s = np.asarray(s)
@@ -159,7 +161,7 @@ def test_monotone_scale_and_old_rows_stay_decodable(kvd):
         dec = np.asarray(kv_quant.dequantize(
             pool, s))
         for (p, slot), orig in written.items():
-            step_sz = np.maximum(cur_s[p, 0, :, 0], kv_quant.EPS)
+            step_sz = np.maximum(cur_s[p, :, 0, 0], kv_quant.EPS)
             if kvd == "fp8":
                 # fp8's step is relative (~6% per rounding) and each
                 # scale growth re-encodes once more — bound loosely;
@@ -167,7 +169,7 @@ def test_monotone_scale_and_old_rows_stay_decodable(kvd):
                 tol = np.abs(orig) * 0.25 + step_sz[:, None] * 0.5
             else:
                 tol = np.broadcast_to(step_sz[:, None] * 1.01, orig.shape)
-            assert (np.abs(dec[p, slot] - orig) <= tol).all(), (
+            assert (np.abs(dec[p, :, slot] - orig) <= tol).all(), (
                 kvd, p, slot)
 
 
@@ -178,9 +180,9 @@ def test_identity_reencode_when_scale_unchanged():
     rng = np.random.default_rng(23)
     for kvd in QUANT:
         KV, hd, page, P = 2, 8, 8, 4
-        z = jnp.zeros((P + 1, page, KV, hd),
+        z = jnp.zeros((P + 1, KV, page, hd),
                       kv_quant.pool_jnp_dtype(kvd, "float32"))
-        s0 = jnp.zeros((P + 1, 1, KV, 1), jnp.float32)
+        s0 = jnp.zeros((P + 1, KV, 1, 1), jnp.float32)
         rows = jnp.asarray(rng.normal(size=(P, KV, hd)), jnp.float32)
         pool, s = kv_quant.quantize_scatter_ref(
             z, s0, jnp.arange(P), jnp.zeros(P, jnp.int32), rows, kvd)
@@ -208,10 +210,10 @@ def test_paged_attn_step_backend_parity_int8():
     KV, hd = cfg.num_kv_heads, cfg.head_dim
     x = jnp.asarray(rng.normal(size=(B, S, cfg.d_model)), jnp.float32)
     pool = {
-        "k": jnp.zeros((P + 1, page, KV, hd), jnp.int8),
-        "v": jnp.zeros((P + 1, page, KV, hd), jnp.int8),
-        "k_scale": jnp.zeros((P + 1, 1, KV, 1), jnp.float32),
-        "v_scale": jnp.zeros((P + 1, 1, KV, 1), jnp.float32),
+        "k": jnp.zeros((P + 1, KV, page, hd), jnp.int8),
+        "v": jnp.zeros((P + 1, KV, page, hd), jnp.int8),
+        "k_scale": jnp.zeros((P + 1, KV, 1, 1), jnp.float32),
+        "v_scale": jnp.zeros((P + 1, KV, 1, 1), jnp.float32),
     }
     bt = np.full((B, W), -1, np.int32)
     pos = np.asarray([0, 9, 17], np.int32)
@@ -249,10 +251,10 @@ def test_paged_cache_specs_scale_leaves():
     for kvd in QUANT:
         specs = attn_lib.paged_cache_specs(cfg, 8, 16, kvd)
         assert set(specs) == {"k", "v", "k_scale", "v_scale"}, kvd
-        assert specs["k_scale"].shape == (9, 1, cfg.num_kv_heads, 1)
+        assert specs["k_scale"].shape == (9, cfg.num_kv_heads, 1, 1)
         assert specs["k_scale"].dtype == "float32"
         # scales shard with their pages/heads, replicate the unit axes
-        assert specs["k_scale"].axes == ("pages", None, "kv_heads", None)
+        assert specs["k_scale"].axes == ("pages", "kv_heads", None, None)
     pools = decoder.init_paged_pools(cfg, 8, 16, "int8")
     leaves = jax.tree.leaves(pools)
     dts = {str(x.dtype) for x in leaves}
@@ -330,7 +332,7 @@ def test_tp_pool_pspecs_shard_scales():
     from repro.distributed import tp as tp_lib
 
     cfg = get_config("tinylm-tp")
-    mesh = AbstractMesh((("model", 2),))
+    mesh = AbstractMesh((2,), ("model",))
     fac = tp_lib.PagedTP(cfg, mesh, kv_dtype="int8")
     specs = fac.pool_pspecs(num_pages=8, page_size=16)
     flat = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))

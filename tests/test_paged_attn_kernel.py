@@ -38,9 +38,9 @@ def _mk_case(rng, B, S, H, KV, hd, page, W, window,
     lens = rng.integers(0, (W - 1) * page - S, size=B)
     need = [-(-(int(l) + S) // page) for l in lens]
     P = sum(need) + 2
-    pk = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)),
+    pk = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)),
                      jnp.float32).astype(pool_dtype)
-    pv = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)),
+    pv = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)),
                      jnp.float32).astype(pool_dtype)
     bt = np.full((B, W), -1, np.int32)
     perm = rng.permutation(P)
@@ -123,8 +123,8 @@ def test_inactive_slot_never_touches_real_pages():
     kn = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
     P = 6
-    pk = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(P + 1, page, KV, hd)), jnp.float32)
+    pk = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(P + 1, KV, page, hd)), jnp.float32)
     bt = np.full((B, W), -1, np.int32)
     bt[0, :2] = [3, 0]   # active request WRITES page 0 (pos 5 -> page 1...
     pos = np.asarray([5, 0, 0], np.int32)  # req 0 writes page bt[0,1]=0
@@ -156,9 +156,9 @@ def test_paged_attn_step_backend_parity():
     x = jnp.asarray(rng.normal(size=(B, S, cfg.d_model)), jnp.float32)
     pool = {
         "k": jnp.asarray(rng.normal(
-            size=(P + 1, page, cfg.num_kv_heads, cfg.head_dim)), jnp.float32),
+            size=(P + 1, cfg.num_kv_heads, page, cfg.head_dim)), jnp.float32),
         "v": jnp.asarray(rng.normal(
-            size=(P + 1, page, cfg.num_kv_heads, cfg.head_dim)), jnp.float32),
+            size=(P + 1, cfg.num_kv_heads, page, cfg.head_dim)), jnp.float32),
     }
     bt = np.full((B, W), -1, np.int32)
     pos = np.asarray([0, 9, 17], np.int32)

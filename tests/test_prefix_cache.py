@@ -276,12 +276,19 @@ def test_server_warm_selection_identical_to_cold(tiny):
         srv.submit(prompt, 6, rid=0)  # donor (identical in both modes)
         srv.drain()
         srv.submit(prompt.copy(), 6, rid=1)  # clone
+        # the clone's compacted tree, read while it decodes (a finished
+        # request releases it)
+        while srv.step():
+            live = [r for r in srv.sched.decoding
+                    if r.rid == 1 and r.compacted]
+            if live and mode not in pruned:
+                pruned[mode] = live[0].pruned_host
         outs[mode] = srv.drain()
         if pc:
             assert srv.metrics.prefix_hits > 0
             assert srv.metrics.requests[1].prefix_hit_tokens > 0
-        pruned[mode] = srv.sched.finished[1].pruned_host
     assert outs["warm"] == outs["cold"]
+    assert jax.tree.leaves(pruned["cold"])
     for c, w in zip(jax.tree.leaves(pruned["cold"]),
                     jax.tree.leaves(pruned["warm"])):
         np.testing.assert_array_equal(np.asarray(c), np.asarray(w))

@@ -537,12 +537,13 @@ def test_paged_kv_gather_shapes():
     from repro.kernels import ops
 
     rng = np.random.default_rng(1)
-    pool = jnp.asarray(rng.normal(size=(9, 4, 2, 16)), jnp.float32)
+    # [P, KV, page, hd] pool -> token-major [B, n*page, KV, hd] view
+    pool = jnp.asarray(rng.normal(size=(9, 2, 4, 16)), jnp.float32)
     bt = jnp.asarray([[0, 3, -1], [8, 2, 1]], jnp.int32)
     out = ops.paged_kv_gather(pool, bt)
     assert out.shape == (2, 12, 2, 16)
     np.testing.assert_array_equal(np.asarray(out[1, 4:8]),
-                                  np.asarray(pool[2]))
+                                  np.asarray(pool[2]).transpose(1, 0, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -70,7 +70,7 @@ def test_pruned_pspecs_shard_compacted_ffn():
 
     cfg = get_config("tinylm-tp")
     # AbstractMesh: spec resolution needs axis names/sizes, no devices
-    mesh = AbstractMesh((("model", 2),))
+    mesh = AbstractMesh((2,), ("model",))
     fac = tp_lib.PagedTP(cfg, mesh)
     D, k, L, B = cfg.d_model, 256, 4, 3
     z = np.zeros
@@ -96,7 +96,7 @@ def test_pruned_pspecs_reject_indivisible_k():
     from repro.distributed import tp as tp_lib
 
     cfg = get_config("tinylm-tp")
-    mesh = AbstractMesh((("model", 2),))
+    mesh = AbstractMesh((2,), ("model",))
     fac = tp_lib.PagedTP(cfg, mesh)
     pruned = {"seg0": {"pos0": {
         "w1": np.zeros((4, 3, cfg.d_model, 255), np.float32),

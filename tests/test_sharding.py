@@ -8,13 +8,9 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.distributed.sharding import make_paged_tp_rules, make_rules, spec_for
 from repro.models.param import ParamSpec
 
-def _abstract_mesh(axis_sizes, axis_names):
-    """Installed JAX takes ``shape_tuple`` of (name, size) pairs."""
-    return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_resolution():
