@@ -51,10 +51,7 @@ def paged_kv_gather(pool, block_tables):
     The kernel copies whole pages (every head's [page, hd] tile); the
     token-major view is one transpose of the gathered pages.
     """
-    _, KV, page, hd = pool.shape
-    B, n = block_tables.shape
-    out = paged_gather(pool, block_tables)  # [B, n, KV, page, hd]
-    return out.transpose(0, 1, 3, 2, 4).reshape(B, n * page, KV, hd)
+    return ref.kv_token_major(paged_gather(pool, block_tables))
 
 
 def paged_attention(q, k_new, v_new, pool_k, pool_v, block_tables, pos,
@@ -79,4 +76,5 @@ griffin_ffn_ref = ref.griffin_ffn_ref
 expert_stat_ref = ref.expert_stat_ref
 glu_ffn_ref = ref.glu_ffn_ref
 paged_gather_ref = ref.paged_gather_ref
+paged_kv_gather_ref = ref.paged_kv_gather_ref
 paged_attn_ref = ref.paged_attn_ref

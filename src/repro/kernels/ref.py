@@ -59,6 +59,19 @@ def paged_gather_ref(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     return jnp.take(pool, jnp.clip(block_tables, 0), axis=0)
 
 
+def kv_token_major(pages: jax.Array) -> jax.Array:
+    """Gathered KV pages [B, n, KV, page, hd] -> the request's
+    token-major view [B, n*page, KV, hd]."""
+    B, n, KV, page, hd = pages.shape
+    return pages.transpose(0, 1, 3, 2, 4).reshape(B, n * page, KV, hd)
+
+
+def paged_kv_gather_ref(pool: jax.Array,
+                        block_tables: jax.Array) -> jax.Array:
+    """pool [P, KV, page, hd]; block_tables [B, n] -> [B, n*page, KV, hd]."""
+    return kv_token_major(paged_gather_ref(pool, block_tables))
+
+
 def paged_attn_ref(
     q: jax.Array,            # [B, S, H, hd]
     k_new: jax.Array,        # [B, S, KV, hd]
@@ -121,13 +134,8 @@ def paged_attn_ref(
         new_v = pool_v.at[gpf, :, off].set(
             v_new.reshape(B * S, KV, hd).astype(pool_v.dtype)
         )
-
-    def view(pool):  # [B, W, KV, page, hd] -> [B, W*page, KV, hd]
-        g = paged_gather_ref(pool, block_tables).transpose(0, 1, 3, 2, 4)
-        return g.reshape(B, W * page, KV, hd)
-
-    k_cache = view(new_k)
-    v_cache = view(new_v)
+    k_cache = paged_kv_gather_ref(new_k, block_tables)
+    v_cache = paged_kv_gather_ref(new_v, block_tables)
     if quantized:
         k_cache = kv_quant.dequantize(
             k_cache, kv_quant.gather_scales(new_sk, block_tables, page)

@@ -319,14 +319,11 @@ def _gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     On TPU this is the Pallas paged-gather kernel (scalar-prefetched
     block ids drive the BlockSpec index map); off-TPU a plain take.
     """
-    if jax.default_backend() == "tpu":
-        from repro.kernels import ops
+    from repro.kernels import ops
 
+    if jax.default_backend() == "tpu":
         return ops.paged_kv_gather(pool, block_tables)
-    B, n = block_tables.shape
-    _, KV, page, hd = pool.shape
-    g = jnp.take(pool, jnp.clip(block_tables, 0), axis=0)  # [B, n, KV, page, hd]
-    return g.transpose(0, 1, 3, 2, 4).reshape(B, n * page, KV, hd)
+    return ops.paged_kv_gather_ref(pool, block_tables)
 
 
 def resolve_attn_backend(backend: str) -> str:

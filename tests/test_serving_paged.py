@@ -92,8 +92,6 @@ def test_paged_decode_bitexact_vs_contiguous(tiny):
 
     ref_logits, aux = decoder.forward(params, cfg, toks[:, :S], want_kv=True,
                                       remat=False, logits_mode="last")
-    cache = decoder.init_cache(cfg, 1, W * page)
-    cache = decoder.fill_cache_from_prefill(cfg, cache, aux.kv)
 
     pools = decoder.init_paged_pools(cfg, 16, page)
     bt = np.full((1, W), -1, np.int32)
@@ -101,6 +99,21 @@ def test_paged_decode_bitexact_vs_contiguous(tiny):
     bt[0, :need] = np.arange(need)
     last, pools, _ = _paged_prefill(cfg, params, pools, bt, toks[:, :S], 8)
     assert float(jnp.max(jnp.abs(last[:, -1] - ref_logits[:, 0]))) < 1e-5
+
+    # chunked prefill and the full forward agree on K/V to fp32 rounding
+    # only (softmax over other lengths); the decode steps compared below
+    # start from the same bits: the prefix the paged prefill wrote
+    def pages_to_seq(buf):  # [L, P+1, KV, page, hd] -> [L, 1, S, KV, hd]
+        L, _, KV, _, hd = buf.shape
+        g = jnp.take(buf, jnp.asarray(bt[0, :need]), axis=1)
+        g = g.transpose(0, 1, 3, 2, 4).reshape(L, 1, need * page, KV, hd)
+        return g[:, :, :S]
+
+    kv = jax.tree.map(pages_to_seq, pools)
+    for got, want in zip(jax.tree.leaves(kv), jax.tree.leaves(aux.kv)):
+        assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    cache = decoder.init_cache(cfg, 1, W * page)
+    cache = decoder.fill_cache_from_prefill(cfg, cache, kv)
 
     pos = S
     for t in range(G):
@@ -544,6 +557,8 @@ def test_paged_kv_gather_shapes():
     assert out.shape == (2, 12, 2, 16)
     np.testing.assert_array_equal(np.asarray(out[1, 4:8]),
                                   np.asarray(pool[2]).transpose(1, 0, 2))
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ops.paged_kv_gather_ref(pool, bt)))
 
 
 # ---------------------------------------------------------------------------
