@@ -56,17 +56,6 @@ def parse_mesh(spec: str):
         )
 
 
-def parse_range(spec: str):
-    """``LO:HI`` -> (LO, HI) inclusive token counts."""
-    try:
-        lo, hi = (int(v) for v in spec.split(":"))
-    except ValueError:
-        raise argparse.ArgumentTypeError(f"want LO:HI, got {spec!r}")
-    if not 0 < lo <= hi:
-        raise argparse.ArgumentTypeError(f"want 0 < LO <= HI, got {spec!r}")
-    return lo, hi
-
-
 def main(argv: Optional[List[str]] = None) -> Optional[dict]:
     """Serve synthetic requests (or HTTP).  Returns, for a drained
     synthetic run on the paged path, ``{"cfg", "requests" [(prompt,
@@ -82,13 +71,6 @@ def main(argv: Optional[List[str]] = None) -> Optional[dict]:
                          "widths, for CPU runs) instead of the published "
                          "one")
     ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--prompt-len", type=parse_range, default=None,
-                    metavar="LO:HI",
-                    help="synthetic prompt lengths, uniform in [LO, HI] "
-                         "(default 16:max_len/2-1)")
-    ap.add_argument("--max-new", type=int, default=None, metavar="N",
-                    help="tokens generated per synthetic request "
-                         "(default uniform in [8, 32))")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
@@ -153,11 +135,6 @@ def main(argv: Optional[List[str]] = None) -> Optional[dict]:
                          "fused attention shard along KV heads, GRIFFIN "
                          "experts along the FF hidden axis; output is "
                          "token-identical to single-device serving")
-    ap.add_argument("--tp-shards", type=int, default=1, metavar="N",
-                    help="balance the GRIFFIN expert selection over N "
-                         "tensor-parallel shards, as --mesh model=N does; "
-                         "on one device this reproduces an N-way sharded "
-                         "run's outputs")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="record the drain as structured spans and write "
@@ -217,8 +194,6 @@ def main(argv: Optional[List[str]] = None) -> Optional[dict]:
     # server forces it on under a mesh either way
     gcfg = None if (args.no_griffin or not cfg.griffin or not cfg.has_ffn) \
         else GriffinConfig(sparsity=args.sparsity)
-    if gcfg is not None and args.tp_shards > 1:
-        gcfg = gcfg.replace(tp_shards=args.tp_shards, per_shard_topk=True)
     profile = None
     if args.sparsity_profile is not None:
         if args.tier is None:
@@ -232,10 +207,9 @@ def main(argv: Optional[List[str]] = None) -> Optional[dict]:
         ap.error("--tier requires GRIFFIN (drop --no-griffin)")
     corpus = SyntheticCorpus(vocab=cfg.vocab_size, seed=0)
     rng = np.random.default_rng(0)
-    lo, hi = args.prompt_len or (16, args.max_len // 2 - 1)
     reqs = [
-        (corpus.sample(int(rng.integers(lo, hi + 1)), seed=500 + rid),
-         args.max_new or int(rng.integers(8, 32)))
+        (corpus.sample(int(rng.integers(16, args.max_len // 2)), seed=500 + rid),
+         int(rng.integers(8, 32)))
         for rid in range(args.requests)
     ]
 

@@ -21,9 +21,8 @@ from repro.launch import compile_cache  # noqa: E402
 
 TINY_SERVE = [
     "--arch", "yi-9b", "--smoke", "--layers", "2", "--kernel-backend",
-    "fused", "--requests", "3", "--slots", "2", "--prompt-len", "20:60",
-    "--max-new", "6", "--max-len", "128", "--page-size", "16",
-    "--num-pages", "64",
+    "fused", "--requests", "3", "--slots", "2", "--max-len", "128",
+    "--page-size", "16", "--num-pages", "64",
 ]
 
 
@@ -60,8 +59,8 @@ def test_one_chip_phases_tiny(monkeypatch, capsys):
                               attn_cfg=get_config("yi-9b", smoke=True))
     out = capsys.readouterr().out
     assert out.count("pool pages bit-identical: True") == 2
-    assert "[griffin tier 0.5] served 3 requests, 18 tokens" in out
-    assert "[dense] served 3 requests, 18 tokens" in out
+    assert "[griffin tier 0.5] served 3 requests, 45 tokens" in out
+    assert "[dense] served 3 requests, 45 tokens" in out
 
 
 @pytest.mark.slow

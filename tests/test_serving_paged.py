@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs.registry import get_config
 from repro.core import GriffinConfig
+from repro.kernels import ops
 from repro.models import decoder
 from repro.serving.metrics import ServingMetrics
 from repro.serving.paged import BlockAllocator, BlockTable, PagedConfig
@@ -92,28 +93,38 @@ def test_paged_decode_bitexact_vs_contiguous(tiny):
 
     ref_logits, aux = decoder.forward(params, cfg, toks[:, :S], want_kv=True,
                                       remat=False, logits_mode="last")
+    cache = decoder.init_cache(cfg, 1, W * page)
+    cache = decoder.fill_cache_from_prefill(cfg, cache, aux.kv)
 
-    pools = decoder.init_paged_pools(cfg, 16, page)
     bt = np.full((1, W), -1, np.int32)
     need = -(-S // page)
     bt[0, :need] = np.arange(need)
+
+    def written(pools):  # the K/V the paged prefill wrote, [L, 1, S, KV, hd]
+        return jax.tree.map(
+            lambda buf: jax.vmap(ops.paged_kv_gather_ref, (0, None))(
+                buf, jnp.asarray(bt[:, :need]))[:, :, :S], pools)
+
+    # 8-token chunks: each chunk's rows and keys are fewer than the
+    # forward's, so the fp32 sums may group differently (CPU-dependent,
+    # ~1e-6 at layers >= 1 on AVX-512 hosts)
+    pools = decoder.init_paged_pools(cfg, 16, page)
     last, pools, _ = _paged_prefill(cfg, params, pools, bt, toks[:, :S], 8)
     assert float(jnp.max(jnp.abs(last[:, -1] - ref_logits[:, 0]))) < 1e-5
-
-    # chunked prefill and the full forward agree on K/V to fp32 rounding
-    # only (softmax over other lengths); the decode steps compared below
-    # start from the same bits: the prefix the paged prefill wrote
-    def pages_to_seq(buf):  # [L, P+1, KV, page, hd] -> [L, 1, S, KV, hd]
-        L, _, KV, _, hd = buf.shape
-        g = jnp.take(buf, jnp.asarray(bt[0, :need]), axis=1)
-        g = g.transpose(0, 1, 3, 2, 4).reshape(L, 1, need * page, KV, hd)
-        return g[:, :, :S]
-
-    kv = jax.tree.map(pages_to_seq, pools)
-    for got, want in zip(jax.tree.leaves(kv), jax.tree.leaves(aux.kv)):
+    for got, want in zip(jax.tree.leaves(written(pools)),
+                         jax.tree.leaves(aux.kv)):
         assert float(jnp.max(jnp.abs(got - want))) < 1e-5
-    cache = decoder.init_cache(cfg, 1, W * page)
-    cache = decoder.fill_cache_from_prefill(cfg, cache, kv)
+
+    # One chunk over a table exactly as wide as the prompt's pages runs
+    # the forward's shapes, so it must write the forward's K/V bits; the
+    # paged decode from there must then match the contiguous decode from
+    # the forward's K/V bit-for-bit.
+    pools = decoder.init_paged_pools(cfg, 16, page)
+    _, pools, _ = _paged_prefill(cfg, params, pools, bt[:, :need],
+                                 toks[:, :S], S)
+    for got, want in zip(jax.tree.leaves(written(pools)),
+                         jax.tree.leaves(aux.kv)):
+        assert bool(jnp.array_equal(got, want))
 
     pos = S
     for t in range(G):
@@ -535,8 +546,6 @@ def test_resume_prefill_rebuilds_decode_kv_exactly(tiny):
 # ---------------------------------------------------------------------------
 
 def test_paged_gather_kernel_matches_ref():
-    from repro.kernels import ops
-
     rng = np.random.default_rng(0)
     pool = jnp.asarray(rng.normal(size=(16, 8, 256)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, 16, size=(4, 6)), jnp.int32)
@@ -547,8 +556,6 @@ def test_paged_gather_kernel_matches_ref():
 
 
 def test_paged_kv_gather_shapes():
-    from repro.kernels import ops
-
     rng = np.random.default_rng(1)
     # [P, KV, page, hd] pool -> token-major [B, n*page, KV, hd] view
     pool = jnp.asarray(rng.normal(size=(9, 2, 4, 16)), jnp.float32)
