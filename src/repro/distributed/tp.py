@@ -143,12 +143,6 @@ class PagedTP:
         self.cfg_local = cfg.replace(
             num_heads=cfg.num_heads // n, num_kv_heads=cfg.num_kv_heads // n
         )
-        # logical shard ids for per-shard step-time attribution
-        # (obs.stragglers): single-process SPMD steps are synchronous,
-        # so the host wall time is charged to every shard — an upper
-        # bound per shard; a real multi-host deployment records each
-        # process's own shard time instead
-        self.shard_ids = tuple(range(n))
         self.rules = shlib.make_paged_tp_rules(axis)
         self.param_specs = tree_map_specs(
             lambda s: shlib.spec_for(s.axes, self.rules, mesh, s.shape),

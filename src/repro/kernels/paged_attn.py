@@ -352,6 +352,8 @@ def paged_attn(
         out_shape=tuple(out_shape),
         input_output_aliases=aliases,
         interpret=resolve_interpret(interpret),
+        # the HLO instruction name the benchmark's trace reader matches
+        name="paged_attn",
     )(*operands)
     ctx = outs[0].reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)
     return (ctx.reshape(B, S, H, hd),) + tuple(outs[1:])

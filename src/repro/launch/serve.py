@@ -151,8 +151,9 @@ def main(argv: Optional[List[str]] = None) -> Optional[dict]:
                          "layer; requires GRIFFIN; 0 = off)")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the "
-                         "drain into DIR (with --trace-out, jitted steps "
-                         "also get TraceAnnotation markers)")
+                         "drain into DIR (with --trace-out, every tracer "
+                         "span is also a TraceAnnotation on the "
+                         "profiler's clock)")
     ap.add_argument("--http", default=None, metavar="HOST:PORT",
                     help="serve over HTTP instead of draining synthetic "
                          "requests: POST /v1/generate streams tokens as "

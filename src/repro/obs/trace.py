@@ -5,10 +5,12 @@ Two span families, matching how a drain decomposes:
 * **Tick spans** — synchronous, nested, recorded as Chrome "complete"
   (``ph: "X"``) events: one ``tick`` span per ``PagedServer.step()``
   with ``plan`` / ``cow_copy`` / ``prefill_chunk`` / ``decode`` /
-  ``spec_round`` / ``flocking_probe`` children.  ``plan`` is pure
-  host-side scheduling; the dispatch children block on device results,
-  so their duration is host+device wall time — the breakdown the
-  "why was this drain slow" question needs.
+  ``spec_round`` / ``flocking_probe`` children, which in turn split
+  into the dispatch (``decode_dispatch``, ``prefill_dispatch``, ...),
+  the wait for the device (``device_wait``), the logits copy to the
+  host (``logits_d2h``) and GRIFFIN host work (``compact``,
+  ``slot_install``).  Each X event names its enclosing span in
+  ``args.parent``.
 * **Request spans** — asynchronous (``ph: "b"/"n"/"e"``), keyed by
   request id, spanning submit to finish with instants for prefill
   chunks, first token, spec rounds, preemptions, COW forks and prefix
@@ -19,6 +21,10 @@ Two span families, matching how a drain decomposes:
 Timestamps: the recorder stores microseconds relative to the first
 event (Chrome traces want small positive ``ts``).  The clock is
 injectable — tests drive virtual time and get byte-identical traces.
+With ``annotate_jax`` every span also opens a
+``jax.profiler.TraceAnnotation`` of its own name for its lifetime, so
+a ``jax.profiler`` trace holds the same span tree on the device ops'
+clock.
 
 Disabled path: ``NULL_TRACER`` is a singleton whose ``span()`` returns
 a shared no-op context manager and whose event buffer is an immutable
@@ -43,7 +49,7 @@ _TID_OBS = 2  # counter samples
 
 class _Span:
     """Context manager recording one complete ("X") event on exit."""
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_parent", "_annot")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -53,21 +59,34 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tr.clock()
+        tr = self._tr
+        stack = tr._open
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._annot = None
+        if tr._profiler is not None:
+            self._annot = tr._profiler.TraceAnnotation(self.name)
+            self._annot.__enter__()
+        self._t0 = tr.clock()
         # anchor the epoch at entry: the X event is recorded on *exit*,
         # and anchoring there would give the first (outermost) span a
         # negative ts relative to a child that exited earlier
-        if self._tr._epoch is None:
-            self._tr._epoch = self._t0
+        if tr._epoch is None:
+            tr._epoch = self._t0
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
         t1 = tr.clock()
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
+        tr._open.pop()
         ev = {"ph": "X", "name": self.name, "cat": self.cat,
               "pid": _PID, "tid": _TID_STEP,
               "ts": tr._us(self._t0), "dur": max(0.0, (t1 - self._t0) * 1e6)}
-        if self.args:
+        if self._parent is not None:
+            ev["args"] = dict(self.args or (), parent=self._parent)
+        elif self.args:
             ev["args"] = self.args
         tr._push(ev)
         return False
@@ -83,10 +102,15 @@ class Tracer:
                  annotate_jax: bool = False):
         self.clock = clock
         self.max_events = max_events
-        self.annotate_jax = annotate_jax
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
         self._epoch: Optional[float] = None
+        self._open: List[str] = []  # names of the spans now open
+        self._profiler = None  # jax.profiler, with annotate_jax
+        if annotate_jax:
+            import jax.profiler
+
+            self._profiler = jax.profiler
 
     # -- internals ---------------------------------------------------------
     def _us(self, t: Optional[float] = None) -> float:
@@ -144,15 +168,6 @@ class Tracer:
             ev["args"] = args
         self._push(ev)
 
-    # -- optional jax.profiler bridge --------------------------------------
-    def jax_annotation(self, name: str):
-        """``TraceAnnotation`` context for the jitted step, visible in
-        ``jax.profiler`` timelines; a no-op unless ``annotate_jax``."""
-        if not self.annotate_jax:
-            return nullcontext()
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-
 
 class NullTracer:
     """Disabled tracer: every hook is a no-op, nothing allocates.
@@ -185,9 +200,6 @@ class NullTracer:
 
     def aend(self, *a: Any, **k: Any) -> None:
         pass
-
-    def jax_annotation(self, name: str):
-        return self._NULL_CTX
 
 
 NULL_TRACER = NullTracer()

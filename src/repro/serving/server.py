@@ -472,14 +472,7 @@ class PagedServer:
             for rid in [r for r in self.flocking.live_rids()
                         if r in self.sched.finished]:
                 self.flocking.on_finish(rid)
-        dur = metrics.clock() - t0
-        shard_times = None
-        if self.tp is not None:
-            shard_times = {i: dur for i in self.tp.shard_ids}
-        self.steps_mon.on_tick(dur, shard_times)
-        tr.counter("pool", occupancy=self.sched.pool_in_use_frac(),
-                   decode_batch=len(plan.decode),
-                   shared_pages=self.sched.alloc.num_shared)
+        self.steps_mon.on_tick(metrics.clock() - t0)
         return self.sched.has_work
 
     def cancel(self, rid: int, reason: str = "cancelled") -> bool:
@@ -571,7 +564,8 @@ class PagedServer:
         use_pruned = work.use_pruned and not self.spec_k \
             and req.pruned_host is not None  # tier 1.0 rebuilds dense
         pruned = self._expand_b1(req.pruned_host) if use_pruned else None
-        with self.tracer.jax_annotation("prefill_chunk"):
+        tr = self.tracer
+        with tr.span("prefill_dispatch"):
             logits, self.pools, stats = self._prefill(
                 self.params, self.pools, jnp.asarray(bt), jnp.asarray(toks),
                 jnp.asarray(pos), jnp.asarray(mask), pruned, collect,
@@ -590,7 +584,7 @@ class PagedServer:
             )
         first_token = None
         if work.is_last and not req.generated:
-            first_token = int(np.argmax(np.asarray(logits)[0, Lc - 1]))
+            first_token = int(np.argmax(self._fetch(logits)[0, Lc - 1]))
         self.sched.finish_prefill_chunk(work, first_token)
         if work.is_last and req.state == DECODING and self.gcfg is not None:
             if not req.compacted:
@@ -613,10 +607,12 @@ class PagedServer:
                     # per-layer budgets + shard-aware compaction behind
                     # one entry point (griffin.select_and_compact);
                     # ks=None is bit-identical to the legacy global
-                    # select_tree + compact_tree path
-                    req.pruned_host, req.k_widths = \
-                        griffin_lib.select_and_compact(
-                            req.s_sq_acc, ffn_tree, self.gcfg, ks=ks)
+                    # select_tree + compact_tree path.  The span times
+                    # the host work; its device ops land in the next wait
+                    with tr.span("compact", rid=req.rid, tier=tier):
+                        req.pruned_host, req.k_widths = \
+                            griffin_lib.select_and_compact(
+                                req.s_sq_acc, ffn_tree, self.gcfg, ks=ks)
                     if self.flocking is not None and tier is None:
                         # frozen selection + the statistic it was made
                         # from, captured before the accumulator drops
@@ -685,18 +681,35 @@ class PagedServer:
                     gmask[r.slot] = mask[r.slot]
             self._count_attn_bytes([r.cache_len for r in group], 1, W,
                                    rows=B)
-            with self.tracer.jax_annotation("decode_step"):
+            with self.tracer.span("decode_dispatch"):
                 logits, self.pools = self._decode(
                     self.params, self.pools, jnp.asarray(bts),
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(gmask),
                     pr,
                 )
-            logits = np.asarray(logits)  # [slots, 1, V]
+            logits = self._fetch(logits)  # [slots, 1, V]
             for r in group:
                 logits_by_slot[r.slot] = logits[r.slot]
         for req in reqs:
             self.sched.finish_decode_token(
                 req, int(np.argmax(logits_by_slot[req.slot][0])))
+
+    def _fetch(self, *outs):
+        """A step's device outputs as host arrays (one array, or a list
+        for several): the wait for the device and the copy to the host,
+        each in its own span.  The host needs these values, so the wait
+        is the one ``np.asarray`` would make anyway; the copy is queued
+        first so that it starts when the step ends, as ``np.asarray``'s
+        would, not when the host wakes from the wait."""
+        tr = self.tracer
+        for o in outs:
+            o.copy_to_host_async()
+        with tr.span("device_wait"):
+            for o in outs:
+                o.block_until_ready()
+        with tr.span("logits_d2h"):
+            host = [np.asarray(o) for o in outs]
+        return host[0] if len(host) == 1 else host
 
     # -- flocking telemetry (obs/flocking.py) ------------------------------
     def _run_flocking_probe(self, reqs: List[ScheduledRequest]) -> None:
@@ -710,11 +723,10 @@ class PagedServer:
         if not probed or self._probe is None:
             return
         toks, pos, mask, bts, _ = self._decode_inputs(probed)
-        with self.tracer.jax_annotation("flocking_probe"):
-            stats = self._probe(
-                self.params, self.pools, jnp.asarray(bts),
-                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(mask),
-            )
+        stats = self._probe(
+            self.params, self.pools, jnp.asarray(bts),
+            jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(mask),
+        )
         part = decoder.prune_stats_tree(stats, self.cfg)
         part = jax.tree.map(np.asarray, part)
         results = self.flocking.on_probe(
@@ -851,15 +863,15 @@ class PagedServer:
                 pos[s] = base[req.rid]
                 kr_arr[s] = ks[req.rid]
                 live_arr[s] = True
-            with self.tracer.jax_annotation("draft_verify"):
+            with self.tracer.span("draft_verify"):
                 dr, vlogits, self.pools = self._draft_verify(
                     self.params, self.pools, jnp.asarray(btsd),
                     jnp.asarray(toks), jnp.asarray(pos),
                     jnp.asarray(kr_arr), jnp.asarray(live_arr),
                     pruned_slots, n_scan,
                 )
-            dr = np.asarray(dr)  # [slots, num_steps]
-            vlogits = np.asarray(vlogits)  # [slots, K+1, V]
+            # [slots, num_steps], [slots, K+1, V]
+            dr, vlogits = self._fetch(dr, vlogits)
             for req in reqs:
                 draft[req.rid] = [int(t)
                                   for t in dr[req.slot, : ks[req.rid]]]
@@ -907,12 +919,12 @@ class PagedServer:
                 vtoks[s, 1 : kr + 1] = draft[req.rid]
                 vpos[s] = base[req.rid]
                 vmask[s, : kr + 1] = True
-            with self.tracer.jax_annotation("verify_step"):
+            with self.tracer.span("verify_step"):
                 vlogits, self.pools = self._verify(
                     self.params, self.pools, bts_j, jnp.asarray(vtoks),
                     jnp.asarray(vpos), jnp.asarray(vmask),
                 )
-            vlogits = np.asarray(vlogits)  # [slots, K+1, V]
+            vlogits = self._fetch(vlogits)  # [slots, K+1, V]
 
         # commit accepted tokens through the vanilla callbacks.  The
         # round telemetry fires *before* the commits: the last commit
@@ -992,13 +1004,19 @@ class PagedServer:
             return None
         widths = self._tick_widths(pruned_reqs)
         sig = tuple(sorted(widths.items()))
-        if sig != self._bucket_sig:
+        rebuilt = sig != self._bucket_sig
+        if rebuilt:
             self._bucket_sig = sig
             self.pruned_slots = None
             self._slot_rid = {}
+        new = [r for r in pruned_reqs if self._slot_rid.get(r.slot) != r.rid]
+        if not new:
+            return self.pruned_slots
         shards = self.gcfg.tp_shards
-        for r in pruned_reqs:
-            if self._slot_rid.get(r.slot) != r.rid:
+        # host work only: the writes' device ops land in the next wait
+        with self.tracer.span("slot_install", installed=len(new),
+                              rebuilt=rebuilt):
+            for r in new:
                 self._install_pruned(
                     r.slot,
                     griffin_lib.pad_pruned_tree(r.pruned_host, widths,

@@ -3,6 +3,8 @@ metrics, straggler detection, flocking telemetry.
 
 Covers the exporter's structural invariants (X-span nesting, async
 request chains, Chrome schema) with virtual-clock determinism, the
+profiler annotations every span opens, the span tree inside a served
+tick (dispatch, device wait, logits copy, compaction, slot installs), the
 streaming histograms' exactness contract (means/totals identical to the
 per-step values, quantiles within one bucket width of exact
 percentiles on a recorded drain), the abort-reason split and
@@ -135,6 +137,46 @@ def test_validator_catches_corruption():
         lambda evs: evs.append({"ph": "n", "name": "late", "id": 5,
                                 "cat": "request", "pid": 1, "tid": 1,
                                 "ts": 1e12})))
+
+
+class _AnnotationRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit with the annotation's name."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.mark.parametrize("make,annotated", [
+    (lambda: Tracer(clock=FakeClock(), annotate_jax=True), True),
+    (lambda: Tracer(clock=FakeClock()), False),
+    (lambda: NULL_TRACER, False),
+], ids=["annotated", "not_annotated", "null"])
+def test_spans_open_profiler_annotations(monkeypatch, make, annotated):
+    """With ``annotate_jax`` each span is also a profiler annotation of
+    its own name, opened and closed in nesting order, so the span tree
+    lies on the device trace's clock; otherwise none is opened."""
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _AnnotationRecorder)
+    monkeypatch.setattr(_AnnotationRecorder, "log", [])
+    tr = make()
+    _drive(tr)
+    want = [("enter", "tick"), ("enter", "plan"), ("exit", "plan"),
+            ("enter", "decode"), ("exit", "decode"), ("exit", "tick")]
+    assert _AnnotationRecorder.log == (want if annotated else [])
+    parents = {e["name"]: e.get("args", {}).get("parent")
+               for e in tr.events if e["ph"] == "X"}
+    if tr.enabled:
+        assert parents == {"tick": None, "plan": "tick", "decode": "tick"}
 
 
 def test_tracer_bounded_buffer_drops():
@@ -311,18 +353,6 @@ def test_straggler_tick_flagging_and_throttle(caplog):
                          buckets=mon.tick_seconds.bounds).count == 10
 
 
-def test_straggler_host_detection():
-    """Per-shard times feed the seed's dormant EWMA detector: a host
-    consistently 10x the fleet median gets flagged after ``patience``
-    windows and surfaces on the gauge."""
-    reg = Registry()
-    mon = StepTimeMonitor(reg, min_ticks=1000)  # tick layer quiet
-    for _ in range(6):
-        mon.on_tick(1e-3, shard_times={0: 1e-3, 1: 1e-3, 2: 10e-3})
-    assert mon.straggler_hosts.value >= 1.0
-    assert 2 in mon.detector.evaluate()
-
-
 # ---------------------------------------------------------------------------
 # End-to-end: traced drains reconcile with metrics, hooks don't perturb
 # ---------------------------------------------------------------------------
@@ -392,6 +422,68 @@ def test_trace_reconciles_with_metrics_exactly(tiny, tmp_path):
     assert validate_chrome_trace(json.loads(path.read_text())) == []
     # exposition validates with the drain's numbers synced in
     assert validate_prometheus_text(srv.metrics.prometheus_text()) == []
+
+
+def _inside(events, parent):
+    """X events that lie inside ``parent``'s interval and name it as
+    their enclosing span."""
+    t0, t1 = parent["ts"], parent["ts"] + parent["dur"]
+    return [e for e in events if e is not parent
+            and t0 <= e["ts"] and e["ts"] + e["dur"] <= t1
+            and e.get("args", {}).get("parent") == parent["name"]]
+
+
+@pytest.mark.parametrize("tier", [0.5, 1.0])
+def test_tick_span_tree(tiny, tier):
+    """A traced drain splits each ``decode`` span into the dispatch, the
+    wait for the device and the logits copy; a compacted tier records
+    one ``compact`` per request and a ``slot_install`` whose
+    ``installed`` args count every slot written, and the dense tier
+    records neither.  No pool counter and no straggler-host gauge."""
+    cfg, params = tiny
+    tr = Tracer()
+    srv = PagedServer(cfg, params,
+                      gcfg=GriffinConfig(sparsity=0.5, per_shard_topk=False),
+                      page_size=8, num_pages=48, n_slots=2,
+                      prefill_chunk=16, max_len=96, tracer=tr,
+                      default_tier=tier)
+    installs = []
+    orig = srv._install_pruned
+
+    def counting(slot, pruned1):
+        installs.append(slot)
+        orig(slot, pruned1)
+
+    srv._install_pruned = counting
+    prompts = _prompts(cfg, 4)
+    for i, p in enumerate(prompts):
+        srv.submit(p, max_new=6, rid=i)
+    assert set(srv.drain()) == {0, 1, 2, 3}
+
+    x = [e for e in tr.events if e["ph"] == "X"]
+    decodes = [e for e in x if e["name"] == "decode"]
+    assert decodes
+    for d in decodes:
+        kids = {e["name"] for e in _inside(x, d)}
+        assert {"decode_dispatch", "device_wait", "logits_d2h"} <= kids
+    # the first token's fetch is the only wait a prefill chunk makes
+    first = [e for e in x if e["name"] == "device_wait"
+             and e["args"]["parent"] == "prefill_chunk"]
+    assert len(first) == len(prompts)
+    compacts = [e for e in x if e["name"] == "compact"]
+    slot_installs = [e for e in x if e["name"] == "slot_install"]
+    if tier < 1.0:
+        assert sorted(e["args"]["rid"] for e in compacts) == [0, 1, 2, 3]
+        assert all(e["args"]["tier"] == tier for e in compacts)
+        assert installs
+        assert sum(e["args"]["installed"] for e in slot_installs) \
+            == len(installs)
+        assert all(e["args"]["parent"] == "decode" for e in slot_installs)
+    else:
+        assert compacts == [] and slot_installs == [] and installs == []
+    assert not any(e["ph"] == "C" for e in tr.events)
+    assert "serving_straggler_hosts" not in srv.metrics.prometheus_text()
+    assert validate_chrome_trace(chrome_trace(tr)) == []
 
 
 def test_histogram_quantiles_on_recorded_drain(tiny):
