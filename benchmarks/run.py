@@ -286,12 +286,15 @@ def bench_kernels() -> None:
     rng = np.random.default_rng(0)
     B, D, F = 4, 256, 2048
     x = jnp.asarray(rng.normal(size=(B, D)), jnp.float32)
-    ws = [jnp.asarray(rng.normal(size=(F, D)) * 0.05, jnp.float32)
+    ws = [jnp.asarray(rng.normal(size=(1, F, D)) * 0.05, jnp.float32)
           for _ in range(3)]
-    ids = jnp.arange(8, dtype=jnp.int32)
-    us = timeit(lambda: ops.griffin_ffn_decode(x, *ws, ids), iters=3)
+    mask = jnp.ones((B, F), jnp.float32)
+    n_live = 8  # the buffer's first 8 blocks of 128 neurons
+    us = timeit(lambda: ops.griffin_ffn_decode(x, *ws, mask, 0, n_live),
+                iters=3)
     err = float(jnp.max(jnp.abs(
-        ops.griffin_ffn_decode(x, *ws, ids) - ops.griffin_ffn_ref(x, *ws, ids, 128)
+        ops.griffin_ffn_decode(x, *ws, mask, 0, n_live)
+        - ops.griffin_ffn_ref(x, *ws, mask, 0, n_live, 128)
     )))
     emit("kernel_griffin_ffn_interpret", us, f"max_err_vs_ref={err:.2e}")
 
@@ -614,11 +617,11 @@ def bench_serving() -> None:
     decode batch — and the per-request telemetry yields tokens/sec and
     p50/p95 TTFT, with per-request GRIFFIN on vs. off.
 
-    CPU caveat: per-slot compacted FF weights turn the decode FFN into
-    per-request einsums, which XLA:CPU runs slower than one shared dense
-    matmul despite half the FLOPs — the GRIFFIN win here is a TPU HBM-
-    bandwidth effect (each request reads k instead of F neuron rows; see
-    table3's derived v5e numbers and kernels/griffin_ffn.py).
+    CPU caveat: the GRIFFIN decode FFN runs the union kernel
+    (kernels/griffin_ffn.py) in Pallas interpret mode here, slower than
+    one shared dense matmul despite fewer FLOPs — the GRIFFIN win is a
+    TPU HBM-bandwidth effect (a tick reads the union's neuron rows
+    instead of all F; see table3's derived v5e numbers).
     """
     from repro.data.pipeline import SyntheticCorpus
     from repro.serving.server import PagedServer

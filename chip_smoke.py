@@ -18,7 +18,7 @@ served tensor-parallel over four chips and on one chip with the same
 balanced GRIFFIN selection (``GriffinConfig(tp_shards=4)``), both at
 "highest" matmul precision.  Prefill logits must agree within
 ``LOGIT_TOL`` and every greedy token must be identical; the pools and
-slot buffers must be spread over all four devices.
+FF union buffers must be spread over all four devices.
 
 Exits non-zero, with no result line, where JAX finds no TPU.  Wall
 times it prints come from a smoke run, not a benchmark.  The last line
@@ -138,7 +138,7 @@ def _report(label: str, srv, requests, results, seconds: float,
     n_tok = sum(len(v) for v in results.values())
     print(f"[{label}] bytes: params {_gb(srv.params):.3f} GB, "
           f"pools {_gb(srv.pools):.3f} GB, "
-          f"slot buffers {_gb(srv.pruned_slots):.3f} GB")
+          f"FF union buffers {_gb(srv.ffn_union):.3f} GB")
     print(f"[{label}] served {len(results)} requests, {n_tok} tokens; "
           f"compiled {_compiles['n'] - since[0]} programs in "
           f"{_compiles['s'] - since[1]:.1f} s; smoke-run wall time "
@@ -211,11 +211,12 @@ def prefill_logits(srv, prompt) -> np.ndarray:
 
 
 def check_placement(srv, n: int) -> None:
-    """Pools and slot buffers are split over all ``n`` devices."""
+    """Pools and the FF union buffers are split over all ``n``
+    devices."""
     from repro.distributed.tp import pool_shard_bytes
 
-    for name, tree in (("pools", srv.pools), ("slot buffers",
-                                              srv.pruned_slots)):
+    for name, tree in (("pools", srv.pools),
+                       ("FF union buffers", srv.ffn_union)):
         leaves = jax.tree.leaves(tree)
         _check(leaves, name)
         for a in leaves:
@@ -243,7 +244,7 @@ def smoke_mesh(axis: str, n: int, cfg=None, server=MESH_SERVER,
     """The same requests served tensor-parallel over ``axis=n`` and on
     one chip with the same balanced GRIFFIN selection
     (``GriffinConfig(tp_shards=n)``): prefill logits within
-    ``LOGIT_TOL``, every greedy token identical, pools and slot buffers
+    ``LOGIT_TOL``, every greedy token identical, pools and FF union buffers
     split over all ``n`` devices."""
     from repro.configs.registry import get_config
     from repro.core import GriffinConfig

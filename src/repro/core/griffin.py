@@ -26,10 +26,11 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import selector as selector_lib
 
@@ -289,8 +290,8 @@ def compact_tree(ffn_params_tree: Any, idx_tree: Any, shards: int = 1) -> Any:
 
 
 # ---------------------------------------------------------------------------
-# Per-layer profiles + tiers (DESIGN.md section 16): the single
-# selection/compaction entry point every serving path goes through.
+# Per-layer profiles + tiers (DESIGN.md section 16): per-layer budgets
+# and the selection every serving path goes through.
 # ---------------------------------------------------------------------------
 
 def ffn_widths(cfg) -> Dict[str, Tuple[int, int]]:
@@ -351,7 +352,7 @@ def compaction_shards(gcfg: GriffinConfig, k: int, d_ff: int) -> int:
     (sampling, blocks) places experts arbitrarily across shards, where
     the shard-local gather silently picks wrong rows; those fall back to
     the plain (order-preserving) gather, which is correct under TP
-    regardless of placement because the per-slot FF psums over the full
+    regardless of placement because the compacted FF psums over the full
     expert axis.
     """
     sh = gcfg.tp_shards
@@ -377,6 +378,36 @@ def _mask_dead_rows(pruned: Dict, keep: jax.Array) -> Dict:
     return out
 
 
+def select_per_layer(
+    stats_tree: Any,
+    gcfg: GriffinConfig,
+    *,
+    ks: Optional[Dict[str, Tuple[int, ...]]] = None,
+    seq_lens: Optional[jax.Array] = None,
+    rng: Optional[jax.Array] = None,
+) -> Dict[str, List[jax.Array]]:
+    """Expert selection of every FF layer: ``{"seg{i}/{name}": [idx per
+    instance]}`` — one sorted index array per scan instance (one for an
+    unrolled layer), each of its own budget.  ``ks`` comes from
+    ``plan_k_tree`` (None → the global ``gcfg.k_of`` budget everywhere).
+    Selection runs as a static Python loop over instances (trace-safe:
+    per-instance ``k`` stays a Python int under jit)."""
+    out: Dict[str, List[jax.Array]] = {}
+    for seg, layers in stats_tree.items():
+        for name, leaf in layers.items():
+            path = f"{seg}/{name}"
+            s_sq = leaf["s_sq"] if isinstance(leaf, dict) else leaf
+            scan = s_sq.ndim == 3
+            n = s_sq.shape[0] if scan else 1
+            k_list = tuple(ks[path]) if ks is not None else (None,) * n
+            out[path] = [
+                select_experts(s_sq[i] if scan else s_sq, gcfg,
+                               seq_lens=seq_lens, rng=rng, k=k_list[i])
+                for i in range(n)
+            ]
+    return out
+
+
 def select_and_compact(
     stats_tree: Any,
     ffn_tree: Any,
@@ -387,9 +418,10 @@ def select_and_compact(
     rng: Optional[jax.Array] = None,
     on_select=None,
 ) -> Tuple[Any, Dict[str, int]]:
-    """Selection + compaction with per-layer expert budgets — the one
-    entry point for every serving path (server, engine, fused prefill
-    step).
+    """Selection + compaction with per-layer expert budgets — the entry
+    point of the contiguous engines (``serving/engine.py``, the fused
+    prefill step); the paged server selects only
+    (``select_per_layer``) and decodes over a union buffer.
 
     ``stats_tree``/``ffn_tree`` are the pruned-stats and FF-params trees
     (``decoder.prune_stats_tree`` / ``decoder.extract_ffn_tree``
@@ -398,9 +430,7 @@ def select_and_compact(
     ``select_tree`` + ``compact_tree``).  Within a scan-stacked leaf,
     instances with different budgets are padded to the leaf's widest
     selection with dead (zero-``w2``-row) experts, so the stacked buffer
-    keeps one static shape.  Selection runs as a static Python loop over
-    instances (trace-safe: per-instance ``k`` stays a Python int under
-    jit).
+    keeps one static shape.
 
     ``on_select(path, idx_list)`` observes the raw (unpadded)
     per-instance selections (flocking telemetry).
@@ -409,60 +439,104 @@ def select_and_compact(
     """
     out: Dict[str, Any] = {}
     widths: Dict[str, int] = {}
-    for seg, layers in stats_tree.items():
-        out[seg] = {}
-        for name, leaf in layers.items():
-            path = f"{seg}/{name}"
-            s_sq = leaf["s_sq"] if isinstance(leaf, dict) else leaf
-            scan = s_sq.ndim == 3
-            n = s_sq.shape[0] if scan else 1
-            F = s_sq.shape[-1]
-            k_list = tuple(ks[path]) if ks is not None else (None,) * n
-            sels = []
-            for i in range(n):
-                s_i = s_sq[i] if scan else s_sq
-                sels.append(select_experts(s_i, gcfg, seq_lens=seq_lens,
-                                           rng=rng, k=k_list[i]))
-            if on_select is not None:
-                on_select(path, sels)
-            sel_ws = [int(s.shape[0]) for s in sels]
-            k_leaf = max(sel_ws)
-            widths[path] = k_leaf
-            ffn_leaf = ffn_tree[seg][name]
-            prs = []
-            for i in range(n):
-                sh = compaction_shards(gcfg, sel_ws[i], F)
-                # pad to the leaf width; per-shard pad only when the pad
-                # target keeps every shard block whole
-                if sh > 1 and k_leaf % sh:
-                    sh = 1
-                idx_p, keep = selector_lib.pad_selection(
-                    sels[i], k_leaf, F, shards=sh)
-                p_i = (
-                    {kk: v[i] for kk, v in ffn_leaf.items()} if scan
-                    else ffn_leaf
-                )
-                prs.append(_mask_dead_rows(compact(p_i, idx_p, shards=sh),
-                                           keep))
-            out[seg][name] = (
-                {kk: jnp.stack([p[kk] for p in prs]) for kk in prs[0]}
-                if scan else prs[0]
+    sel_tree = select_per_layer(stats_tree, gcfg, ks=ks, seq_lens=seq_lens,
+                                rng=rng)
+    for path, sels in sel_tree.items():
+        seg, name = path.split("/")
+        leaf = stats_tree[seg][name]
+        s_sq = leaf["s_sq"] if isinstance(leaf, dict) else leaf
+        scan, F = s_sq.ndim == 3, s_sq.shape[-1]
+        if on_select is not None:
+            on_select(path, sels)
+        sel_ws = [int(s.shape[0]) for s in sels]
+        k_leaf = max(sel_ws)
+        widths[path] = k_leaf
+        ffn_leaf = ffn_tree[seg][name]
+        prs = []
+        for i, sel in enumerate(sels):
+            sh = compaction_shards(gcfg, sel_ws[i], F)
+            # pad to the leaf width; per-shard pad only when the pad
+            # target keeps every shard block whole
+            if sh > 1 and k_leaf % sh:
+                sh = 1
+            idx_p, keep = selector_lib.pad_selection(sel, k_leaf, F, shards=sh)
+            p_i = (
+                {kk: v[i] for kk, v in ffn_leaf.items()} if scan
+                else ffn_leaf
             )
+            prs.append(_mask_dead_rows(compact(p_i, idx_p, shards=sh),
+                                       keep))
+        out.setdefault(seg, {})[name] = (
+            {kk: jnp.stack([p[kk] for p in prs]) for kk in prs[0]}
+            if scan else prs[0]
+        )
     return out, widths
 
 
-def pad_pruned_tree(
-    pruned: Any, widths: Dict[str, int], shards: int = 1
-) -> Any:
-    """Pad every leaf of a compacted tree to ``widths[path]`` experts
-    (zero ``w2`` rows — bit-exact; see ``ffn.pad_compacted``).  Leaves
-    already at their target width pass through untouched."""
-    from repro.models.layers.ffn import pad_compacted
+# ---------------------------------------------------------------------------
+# The paged server's decode FF (serving/server.py): one buffer per FF
+# layer holds the union of the live requests' selections, and each
+# request keeps a 0/1 mask over it (kernels/griffin_ffn.py).
+# ---------------------------------------------------------------------------
 
+#: neurons per block of the union buffer (the kernel's BlockSpec rows)
+UNION_BLOCK = 128
+
+
+def union_rows(sels: List[np.ndarray], d_ff: int, groups: int = 1,
+               block: int = UNION_BLOCK):
+    """Lay out the union of several selections of one FF layer for the
+    union kernel.
+
+    The buffer has ``groups`` equal parts, one per contiguous F-range
+    (the tensor-parallel shards; 1 on one device), each of capacity
+    ``ceil(d_ff / groups / block)`` blocks.  Part ``j`` holds the union
+    of the selections' indices inside range ``j`` at its head, padded by
+    repeating an index.  Returns ``(idx [C] int32, masks [len(sels), C]
+    float32 — each selection's neurons in the buffer, n_live [groups]
+    int32 — the blocks each part uses, size — |U|)``.
+    """
+    fg = d_ff // groups
+    cap = -(-fg // block) * block
+    idx = np.empty(groups * cap, np.int32)
+    masks = np.zeros((len(sels), groups * cap), np.float32)
+    n_live = np.zeros(groups, np.int32)
+    size = 0
+    for j in range(groups):
+        lo, at = j * fg, j * cap
+        parts = [s[(s >= lo) & (s < lo + fg)] for s in sels]
+        u = np.unique(np.concatenate(parts))
+        c = len(u)
+        idx[at:at + cap] = u[-1] if c else lo
+        idx[at:at + c] = u
+        for r, part in enumerate(parts):
+            masks[r, at:at + c] = np.isin(u, part)
+        n_live[j] = -(-c // block)
+        size += c
+    return idx, masks, n_live, size
+
+
+def gather_union(ffn_tree: Any, idx_tree: Dict[str, Any]) -> Dict:
+    """The union buffers: rows of the FF weights at ``idx_tree[path]``
+    ([n, C], one row per scan instance), neuron-row-major
+    ``{"wg", "w1", "w2": [n, C, D]}`` per layer, nested like
+    ``ffn_tree`` (whose ``wg``/``w1`` are [D, F] and ``w2`` [F, D],
+    with a leading [n] on scan leaves)."""
     out: Dict[str, Any] = {}
-    for seg, layers in pruned.items():
-        out[seg] = {
-            name: pad_compacted(ffn, widths[f"{seg}/{name}"], shards=shards)
-            for name, ffn in layers.items()
+    for path, idx in idx_tree.items():
+        seg, name = path.split("/")
+        p = ffn_tree[seg][name]
+        stack = (lambda v: v) if p["w1"].ndim == 3 else (lambda v: v[None])
+
+        def cols(w, i):  # [D, F] -> [C, D]
+            return jnp.take(w, i, axis=1).T
+
+        def rows(w, i):  # [F, D] -> [C, D]
+            return jnp.take(w, i, axis=0)
+
+        out.setdefault(seg, {})[name] = {
+            "wg": jax.vmap(cols)(stack(p["wg"]), idx),
+            "w1": jax.vmap(cols)(stack(p["w1"]), idx),
+            "w2": jax.vmap(rows)(stack(p["w2"]), idx),
         }
     return out

@@ -286,9 +286,8 @@ def make_paged_tp_rules(axis: str = "model") -> Rules:
 
     Head-parallel attention + FF-hidden-parallel FFN on one mesh axis:
     ``heads``/``kv_heads`` shard the projections and the KV page pools,
-    ``mlp`` shards the FF hidden axis (including GRIFFIN-compacted
-    per-slot expert weights, whose ``k_ff`` the selection pads to a
-    multiple of the axis size).  Everything the host mutates or the
+    ``mlp`` shards the FF hidden axis (including the GRIFFIN union
+    buffer, one part per shard).  Everything the host mutates or the
     shards must agree on — block tables, positions, pages, the embed
     table and LM head — stays replicated, so logits come out replicated
     and the scheduler needs no device-aware logic.

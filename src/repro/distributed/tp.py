@@ -14,13 +14,13 @@ FF-hidden-parallel FFN through the whole paged step (DESIGN.md section
 * **Attention projections** shard along ``heads``/``kv_heads``; the
   out-projection's contraction over heads becomes a partial sum that
   ``sharding.psum_if_tp`` all-reduces (the only attention collective).
-* **FF weights** shard along the hidden axis, including the per-slot
-  GRIFFIN-compacted expert weights: selection pads ``k_ff`` to a
-  multiple of the axis size (``GriffinConfig.k_of``) and balanced
-  per-shard top-k (``selector.select_topk_per_shard``) puts exactly
-  ``k/N`` experts in each shard's contiguous F-range, so the compacted
-  decode runs all-gather-free — one psum after the down-projection,
-  same as the dense path.
+* **FF weights** shard along the hidden axis, including the GRIFFIN
+  union buffer (``serving/server.py``): it is laid out in N parts, part
+  ``j`` holding the union of the live selections inside shard ``j``'s
+  contiguous F-range with its own live count (balanced per-shard top-k,
+  ``selector.select_topk_per_shard``, keeps the parts even), so the
+  union decode runs all-gather-free — one psum after the
+  down-projection, same as the dense path.
 * **Everything else is replicated** (embed table, LM head, norms,
   residual stream), so logits come out replicated and the host
   scheduler/sampler stay device-count-agnostic.
@@ -50,16 +50,15 @@ from repro.distributed.sharding import mesh_axis_size
 from repro.models import decoder
 from repro.models.param import tree_map_specs
 
-# innermost-dict leaf names of a (per-slot) compacted FF tree -> the
-# logical axes of the *trailing* dims; leading dims (slot axis, scan
-# layer axis) are replicated
+# leaf names of a union FF tree (``decoder.decode_step_paged``'s
+# ``pruned``) -> the logical axes of their dims: buffers [n, C, D],
+# masks [n, rows, C], live counts [n, shards]
 _PRUNED_AXES = {
-    "w1": ("embed", "mlp"),
-    "wg": ("embed", "mlp"),
-    "w2": ("mlp", "embed"),
-    "b1": ("mlp",),
-    "bg": ("mlp",),
-    "b2": ("act_embed",),
+    "wg": (None, "mlp", "embed"),
+    "w1": (None, "mlp", "embed"),
+    "w2": (None, "mlp", "embed"),
+    "mask": (None, None, "mlp"),
+    "n_live": (None, "mlp"),
 }
 
 
@@ -105,7 +104,7 @@ class PagedTP:
     """Builds and caches the shard_mapped + jitted paged step functions.
 
     The factory owns the resolved PartitionSpec trees (params, pools,
-    per-slot compacted FF) and the local config; the server calls
+    union FF) and the local config; the server calls
     ``prefill``/``decode``/``verify``/``cow`` exactly like its
     single-device jits (pools donated on every step, so the in-place
     page updates compose with the NamedShardings).
@@ -159,25 +158,20 @@ class PagedTP:
         )
 
     def pruned_pspecs(self, pruned: Any) -> Any:
-        """PartitionSpec tree for a per-slot compacted FF tree (leading
-        slot / scan-layer dims replicated, trailing dims per
-        ``_PRUNED_AXES``).  The compacted width must divide the axis —
-        the selection guarantees it (``GriffinConfig.k_of`` with
-        ``tp_shards``); a non-divisible width here is a config error,
-        not a replicate-fallback case."""
+        """PartitionSpec tree for a union FF tree: buffers, masks and
+        live counts split along the union's N shard parts.  A leaf the
+        axis cannot divide is a layout error, not a replicate-fallback
+        case."""
 
         def leaf(path: str, key: str, arr) -> P:
-            axes = _PRUNED_AXES[key]
-            full = (None,) * (arr.ndim - len(axes)) + axes
-            spec = shlib.spec_for(full, self.rules, self.mesh, arr.shape)
-            if key != "b2" and self.axis not in jax.tree.leaves(tuple(spec)):
+            spec = shlib.spec_for(_PRUNED_AXES[key], self.rules, self.mesh,
+                                  arr.shape)
+            if self.axis not in jax.tree.leaves(tuple(spec)):
                 raise ValueError(
-                    f"compacted FF leaf {path}/{key} with shape {arr.shape} "
-                    f"is not divisible by the {self.axis!r} axis "
-                    f"(size {self.n}) — the divisible-k_ff rule holds per "
-                    f"layer: pass a GriffinConfig with tp_shards={self.n} "
-                    f"(tier budgets pad each layer's k to a multiple, see "
-                    f"griffin.tier_k)."
+                    f"union FF leaf {path}/{key} with shape {arr.shape} "
+                    f"does not divide over the {self.axis!r} axis (size "
+                    f"{self.n}): lay the union out in tp_shards={self.n} "
+                    f"parts (griffin.union_rows groups)."
                 )
             return spec
 
@@ -215,8 +209,8 @@ class PagedTP:
         )
 
     def _pruned_key(self, pruned: Any) -> Any:
-        # structure AND shapes: tier buckets re-size the compacted width
-        # between ticks, and a step factory built for one width must not
+        # structure AND shapes: the decode and resume-prefill trees differ
+        # in their mask rows, and a step factory built for one must not
         # serve another (its in_specs were resolved against the shapes
         # it first saw)
         if pruned is None:

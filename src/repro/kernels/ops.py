@@ -19,11 +19,12 @@ from repro.kernels.paged_attn import paged_attn as _paged_attn
 from repro.kernels.paged_gather import paged_gather as _paged_gather
 
 
-def griffin_ffn_decode(x, wg, w1, w2, block_ids, *, block_size: int = 128,
-                       activation: str = "swiglu"):
-    """Zero-copy pruned decode FFN (see kernels/griffin_ffn.py)."""
+def griffin_ffn_decode(x, wg, w1, w2, mask, layer, n_live, *,
+                       block_size: int = 128, activation: str = "swiglu"):
+    """GRIFFIN decode FFN over a union-compacted buffer, reading only its
+    first ``n_live`` blocks (see kernels/griffin_ffn.py)."""
     return _griffin_ffn(
-        x, wg, w1, w2, block_ids, block_size=block_size,
+        x, wg, w1, w2, mask, layer, n_live, block_size=block_size,
         activation=activation,
     )
 

@@ -15,25 +15,25 @@ def _act(name: str):
 
 
 def griffin_ffn_ref(
-    x: jax.Array,  # [B, D]
-    wg: jax.Array,  # [F, D] (neuron-rows)
-    w1: jax.Array,  # [F, D]
-    w2: jax.Array,  # [F, D]
-    block_ids: jax.Array,  # [nb] int32, block granularity
+    x: jax.Array,  # [R, D]
+    wg: jax.Array,  # [L, C, D] (neuron-rows, stacked over layers)
+    w1: jax.Array,  # [L, C, D]
+    w2: jax.Array,  # [L, C, D]
+    mask: jax.Array,  # [R, C] 0/1 per row
+    layer: int,
+    n_live: int,
     block_size: int,
     activation: str = "swiglu",
 ) -> jax.Array:
-    """GRIFFIN decode FFN: act(x Wg^T) * (x W1^T) @ W2 over selected
-    neuron blocks only.  Returns fp32 [B, D]."""
-    idx = (block_ids[:, None] * block_size
-           + jnp.arange(block_size, dtype=block_ids.dtype)[None, :]).reshape(-1)
-    wg_s = jnp.take(wg, idx, axis=0)
-    w1_s = jnp.take(w1, idx, axis=0)
-    w2_s = jnp.take(w2, idx, axis=0)
-    act = _act(activation)
+    """GRIFFIN decode FFN over a union buffer: act(x Wg^T) * (x W1^T),
+    masked per row, @ W2 over the first ``n_live`` blocks of layer
+    ``layer`` only (rows past them never reach y).  Returns fp32
+    [R, D]."""
+    live = n_live * block_size
+    wg_s, w1_s, w2_s = (w[layer, :live] for w in (wg, w1, w2))
     g = x @ wg_s.T
     h = x @ w1_s.T
-    z = act(g) * h
+    z = _act(activation)(g) * h * mask[:, :live].astype(x.dtype)
     return (z @ w2_s).astype(jnp.float32)
 
 

@@ -735,7 +735,7 @@ def _apply_layer_paged(
     if desc.ffn == "dense":
         h = apply_norm(lp["ffn_norm"], x, cfg)
         if pruned_ffn is not None:
-            y = ffn_lib.ffn_forward_perslot(pruned_ffn, h, cfg)
+            y = ffn_lib.ffn_forward_union(pruned_ffn, h, cfg)
         else:
             z = ffn_lib.ffn_activations(lp["ffn"], h, cfg)
             if collect_stats:
@@ -774,7 +774,7 @@ def decode_step_paged(
     token: jax.Array,  # [B, S] int32 (decode: S=1; prefill chunk: S=chunk)
     pos: jax.Array,  # [B] int32 tokens already cached per request
     write_mask: Optional[jax.Array] = None,  # [B, S] bool
-    pruned: Optional[Dict] = None,  # per-slot compacted FF tree
+    pruned: Optional[Dict] = None,  # union-compacted FF tree
     collect_stats: bool = False,
     backend: str = "gather",
     kv_dtype: str = "fp32",
@@ -783,7 +783,12 @@ def decode_step_paged(
 
     Unifies chunked prefill (B=1, S=chunk, ``collect_stats`` streams the
     GRIFFIN ``s_sq`` statistic per chunk) and batched decode (S=1, one
-    request per slot, ``pruned`` holds per-slot compacted FF weights).
+    request per slot).  ``pruned`` runs the GRIFFIN FF blocks over the
+    server's union-compacted buffers: per FF layer path, ``wg``/``w1``/
+    ``w2`` [n, C, D] (``n`` the segment's scan length, 1 for an
+    unrolled layer), ``mask`` [n, B, C] each row's neurons in the
+    buffer, ``n_live`` [n, shards] the blocks in use
+    (``ffn.ffn_forward_union``).
     ``backend`` picks the attention path per
     ``attention.resolve_attn_backend``: the fused paged-attention
     kernel or the gather-then-attend oracle (default, bit-exact vs the
@@ -803,11 +808,13 @@ def decode_step_paged(
         key = f"seg{i}"
         sp = params[key]
         seg_pool = pools[key]
-        seg_pruned = (pruned or {}).get(key)
+        seg_pruned = (pruned or {}).get(key) or {}
         if seg.kind == "unroll":
             np_seg, st_seg = {}, {}
             for j, desc in enumerate(seg.descs):
-                pf = (seg_pruned or {}).get(f"layer{j}")
+                leaf = seg_pruned.get(f"layer{j}")
+                pf = None if leaf is None else _union_layer(
+                    leaf, 0, {k: leaf[k][0] for k in ("mask", "n_live")})
                 x, npool, st = _apply_layer_paged(
                     sp[f"layer{j}"], desc, seg_pool[f"layer{j}"], x,
                     block_tables, pos, write_mask, cfg, pf, collect_stats,
@@ -819,12 +826,19 @@ def decode_step_paged(
             new_pools[key] = np_seg
             stats_tree[key] = st_seg
         else:
-            def body(x_c, xs, _descs=seg.descs,
-                     _has_pruned=seg_pruned is not None):
-                lp_all, pool_all, pruned_all = xs
+            # the union buffers stay whole (the kernel picks the layer by
+            # index, so no layer slice of them is copied); each layer's
+            # masks and live counts ride the scan
+            rows = {name: {k: leaf[k] for k in ("mask", "n_live")}
+                    for name, leaf in seg_pruned.items()}
+
+            def body(x_c, xs, _descs=seg.descs, _pruned=seg_pruned):
+                lp_all, pool_all, (layer, rows_all) = xs
                 np_out, st_out = {}, {}
                 for j, desc in enumerate(_descs):
-                    pf = pruned_all.get(f"pos{j}") if _has_pruned else None
+                    leaf = _pruned.get(f"pos{j}")
+                    pf = None if leaf is None else _union_layer(
+                        leaf, layer, rows_all[f"pos{j}"])
                     x_c, npool, st = _apply_layer_paged(
                         lp_all[f"pos{j}"], desc, pool_all[f"pos{j}"], x_c,
                         block_tables, pos, write_mask, cfg, pf, collect_stats,
@@ -835,7 +849,8 @@ def decode_step_paged(
                 return x_c, (np_out, st_out)
 
             x, (np_seg, st_seg) = jax.lax.scan(
-                body, x, (sp, seg_pool, seg_pruned or {})
+                body, x,
+                (sp, seg_pool, (jnp.arange(seg.n, dtype=jnp.int32), rows)),
             )
             new_pools[key] = np_seg
             if collect_stats:
@@ -843,6 +858,13 @@ def decode_step_paged(
     x = apply_norm(params["final_norm"], x, cfg)
     logits = lm_logits(params.get("head", {}), params["embed"], x, cfg)
     return logits, new_pools, (stats_tree if collect_stats else None)
+
+
+def _union_layer(leaf: Dict, layer, rows: Dict) -> Dict:
+    """One layer's view of a segment's union FF leaf: the whole stacked
+    buffers, the layer's index in them, its masks and live count."""
+    return {"wg": leaf["wg"], "w1": leaf["w1"], "w2": leaf["w2"],
+            "layer": layer, "mask": rows["mask"], "n_live": rows["n_live"]}
 
 
 def draft_loop_paged(
@@ -853,7 +875,7 @@ def draft_loop_paged(
     token: jax.Array,  # [B, 1] int32: last committed token per slot
     pos: jax.Array,  # [B] int32 committed KV length per slot
     k_r: jax.Array,  # [B] int32 per-slot draft lengths (<= num_steps)
-    pruned: Optional[Dict] = None,  # per-slot compacted FF tree (the draft)
+    pruned: Optional[Dict] = None,  # union-compacted FF tree (the draft)
     *,
     num_steps: int,
     backend: str = "gather",
@@ -863,7 +885,7 @@ def draft_loop_paged(
 
     Runs ``num_steps`` greedy draft iterations of the ``[B, 1]`` paged
     decode step inside a single ``lax.scan`` — argmax feedback, draft-KV
-    page writes, and the per-slot GRIFFIN-compacted FF weights all stay
+    page writes, and the GRIFFIN union-compacted FF buffers all stay
     on device, so a round costs one dispatch and one host sync instead
     of ``num_steps`` of each (the serving-path host loop this replaces;
     ``PagedServer._run_speculative``).
@@ -949,7 +971,7 @@ def draft_verify_paged(
     pos: jax.Array,  # [B] int32 committed KV length per slot
     k_r: jax.Array,  # [B] int32 per-slot draft lengths (0 = no drafting)
     row_live: jax.Array,  # [B] bool: slot holds a planned request
-    pruned: Optional[Dict] = None,  # per-slot compacted FF tree (the draft)
+    pruned: Optional[Dict] = None,  # union-compacted FF tree (the draft)
     *,
     num_steps: int,
     spec_k: int,

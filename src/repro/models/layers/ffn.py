@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain, psum_if_tp
+from repro.kernels.griffin_ffn import griffin_ffn
 from repro.models.common import activation_fn
 from repro.models.param import ParamSpec
 
@@ -112,30 +113,27 @@ def ffn_forward(
     return y, stats
 
 
-def ffn_forward_perslot(params: Dict, x: jax.Array, cfg) -> jax.Array:
-    """FF forward with *per-request* weights (leading slot axis).
+def ffn_forward_union(pruned: Dict, x: jax.Array, cfg) -> jax.Array:
+    """FF forward over the decode tick's union-compacted buffer.
 
-    The paged serving path keeps one GRIFFIN-compacted FF block per
-    decode slot (each request selected its own experts from its own
-    prompt): leaves are [B, D, k] / [B, k, D], x is [B, S, D].
+    The paged serving path keeps one buffer per FF layer holding the
+    union of the live requests' selected neurons (``serving/server.py``);
+    ``pruned`` is one layer's view of it: ``wg``/``w1``/``w2`` the
+    segment's stacked neuron-row buffers [L, C, D] and ``layer`` this
+    layer's index in them, ``mask`` [B, C] each batch row's own neurons,
+    ``n_live`` [1] the blocks in use.  The kernel reads only those
+    blocks (``kernels/griffin_ffn.py``).  x: [B, S, D]; every token of
+    a row takes the row's mask.
     """
-    act = activation_fn(cfg.activation)
-    h1 = jnp.einsum("bsd,bdf->bsf", x, params["w1"])
-    if "b1" in params:
-        h1 = h1 + params["b1"][:, None]
-    if "wg" in params:
-        hg = jnp.einsum("bsd,bdf->bsf", x, params["wg"])
-        if "bg" in params:
-            hg = hg + params["bg"][:, None]
-        z = act(hg) * h1
-    else:
-        z = act(h1)
-    # per-slot compacted experts shard along k (balanced per-shard
-    # selection): the down-projection is a partial sum per shard
-    y = psum_if_tp(jnp.einsum("bsf,bfd->bsd", z, params["w2"]))
-    if "b2" in params:
-        y = y + params["b2"][:, None]
-    return y
+    B, S, D = x.shape
+    mask = pruned["mask"] if S == 1 else jnp.repeat(pruned["mask"], S, axis=0)
+    y = griffin_ffn(
+        x.reshape(B * S, D), pruned["wg"], pruned["w1"], pruned["w2"], mask,
+        pruned["layer"], pruned["n_live"][0], activation=cfg.activation,
+    )
+    # the buffer shards along its neuron axis (each shard holds the union
+    # of its own F-range): the down-projection is a partial sum per shard
+    return psum_if_tp(y).astype(x.dtype).reshape(B, S, D)
 
 
 def compact_ffn_params(params: Dict, idx: jax.Array, shards: int = 1) -> Dict:
@@ -199,52 +197,6 @@ def compact_ffn_params(params: Dict, idx: jax.Array, shards: int = 1) -> Dict:
     if "b2" in params:
         out["b2"] = params["b2"]
     return out
-
-
-# expert-axis position per compacted-FF leaf (negative: leaves may carry
-# leading scan/slot axes); b2 has no expert axis
-_EXPERT_AXIS = {"w1": -1, "wg": -1, "w2": -2, "b1": -1, "bg": -1}
-
-
-def pad_compacted(params: Dict, k_pad: int, shards: int = 1) -> Dict:
-    """Zero-pad a compacted FF block's expert axis from ``k`` to
-    ``k_pad`` (DESIGN.md section 16: mixed-tier ticks bucket every
-    request's buffers to one width so the batch stays one program).
-
-    Zero ``w2`` rows make the padded experts contribute exactly ``0.0``
-    — bit-identical outputs to the natural-width buffers (the zero
-    ``w1``/``wg`` columns and ``b1``/``bg`` entries only feed those dead
-    rows).  ``shards > 1`` pads each contiguous shard block at its own
-    tail so the TP expert-to-device assignment of the real experts is
-    unchanged.
-    """
-    k = params["w2"].shape[-2]
-    if k_pad == k:
-        return dict(params)
-    if k_pad < k:
-        raise ValueError(f"pad_compacted: k_pad {k_pad} < k {k}")
-    if shards > 1 and (k % shards or k_pad % shards):
-        raise ValueError(
-            f"pad_compacted: per-shard padding needs k ({k}) and k_pad "
-            f"({k_pad}) divisible by shards ({shards})"
-        )
-
-    def pad(v, ax):
-        ax = v.ndim + ax
-        if shards == 1:
-            widths = [(0, 0)] * v.ndim
-            widths[ax] = (0, k_pad - k)
-            return jnp.pad(v, widths)
-        shape = v.shape[:ax] + (shards, k // shards) + v.shape[ax + 1:]
-        widths = [(0, 0)] * (v.ndim + 1)
-        widths[ax + 1] = (0, (k_pad - k) // shards)
-        out = jnp.pad(v.reshape(shape), widths)
-        return out.reshape(v.shape[:ax] + (k_pad,) + v.shape[ax + 1:])
-
-    return {
-        name: pad(v, _EXPERT_AXIS[name]) if name in _EXPERT_AXIS else v
-        for name, v in params.items()
-    }
 
 
 def pruned_specs(cfg, k: int, d_ff: Optional[int] = None) -> Dict[str, ParamSpec]:

@@ -12,11 +12,11 @@ GRIFFIN lifecycle per request (the paper's prompt->generation split,
 streamed): every prefill chunk runs the *full* FF blocks and returns the
 chunk's partial ``s_sq`` statistic (eq. 6 is a sum over tokens, so
 chunk-wise accumulation is exact); at the transition to decode the
-accumulated statistic is reduced once (select + compact) and the request
-decodes with its own compacted FF weights from then on.  A preempted
+accumulated statistic is reduced once (selection) and the request
+decodes with only its selected FF neurons from then on.  A preempted
 request is rescheduled recompute-style (pages freed, prefill restarts
-over prompt + generated-so-far) but keeps its compacted weights — the
-expert set stays the one chosen from the original prompt.
+over prompt + generated-so-far) but keeps its selection — the expert
+set stays the one chosen from the original prompt.
 
 Draft/verify phase (self-speculative decoding, see ARCHITECTURE.md):
 when the server runs a speculative tick instead of a one-token decode
@@ -154,12 +154,11 @@ class ScheduledRequest:
     # FF experts this request keeps.  None = legacy global gcfg budget;
     # 1.0 decodes through the dense path (no compaction at all)
     tier: Optional[float] = None
-    # server-managed GRIFFIN payloads (jax trees; opaque to the scheduler)
+    # server-managed GRIFFIN payloads (opaque to the scheduler): the
+    # prompt statistic while it accumulates, then the selected neuron
+    # indices per FF layer (None at tier 1.0)
     s_sq_acc: Any = None
-    pruned_host: Any = None
-    # natural per-layer buffer widths of pruned_host ({path: k}, set at
-    # compaction) — the server's tick bucketing reads these
-    k_widths: Any = None
+    selection: Any = None
 
     @property
     def prefill_tokens(self) -> np.ndarray:
@@ -189,8 +188,8 @@ class PrefillWork:
     is_last: bool
     collect_stats: bool
     # resume path: generated-token positions were originally decoded with
-    # the request's compacted FF weights, so their KV must be rebuilt with
-    # the same weights (chunks never straddle the prompt/generated boundary)
+    # the request's selected FF neurons, so their KV must be rebuilt with
+    # the same neurons (chunks never straddle the prompt/generated boundary)
     use_pruned: bool = False
 
 
@@ -310,7 +309,7 @@ class Scheduler:
 
     def _evict(self, victim: ScheduledRequest) -> None:
         """Recompute-style eviction: free pages, requeue from scratch
-        (a compacted request keeps its frozen expert weights)."""
+        (a compacted request keeps its frozen expert selection)."""
         self.alloc.free_request(victim.rid)
         victim.table = BlockTable()
         victim.slot = None
@@ -410,7 +409,7 @@ class Scheduler:
         req.state = FINISHED
         req.aborted = True
         req.slot = None
-        req.pruned_host = None
+        req.selection = None
         self.finished[req.rid] = req
         if self.spec_ctl is not None:
             self.spec_ctl.forget(req.rid)
@@ -633,9 +632,8 @@ class Scheduler:
         req.table = BlockTable()
         req.state = FINISHED
         req.slot = None
-        # a finished request keeps its tokens, not its compacted FF
-        # weights (device arrays as large as a layer's FF per request)
-        req.pruned_host = None
+        # a finished request keeps its tokens, not its selection
+        req.selection = None
         self.finished[req.rid] = req
         if self.spec_ctl is not None:
             self.spec_ctl.forget(req.rid)

@@ -40,19 +40,31 @@ TPU / gather elsewhere.  Outputs are token-identical either way
 through every jitted step, so XLA updates pages in place instead of
 copying the pool buffers every tick.
 
+GRIFFIN decode (``gcfg``): on a prompt's last chunk the request keeps
+only its selected neuron indices per FF layer (one jitted selection,
+copied to the host once).  Decode slots hold selections
+(``_install_pruned``), and one buffer per FF layer holds the rows of
+the union U of the decode batch's selections, neuron-row-major, with
+capacity d_ff; each slot has a 0/1 mask over U.  The buffer is
+rebuilt (one jitted gather) only when a slot's selection changes, and
+the decode FF kernel (``kernels/griffin_ffn.py``) reads only U's
+blocks: a slot's output is exactly the sum over its own neurons, and a
+tick reads |U| rows per layer instead of one compacted copy per slot.
+The union's size is a runtime scalar, so it never adds a program.
+
 Tensor parallelism (``mesh=...``): every jitted step runs shard_mapped
 over a 1-D ``model`` mesh axis (``distributed/tp.py``, DESIGN.md
 section 11) — KV pools and attention shard along ``kv_heads``
-(per-shard pool bytes exactly 1/N), FF weights (including the per-slot
-GRIFFIN-compacted experts, whose ``k_ff`` the selection pads to a
-multiple of N) along the hidden axis, block tables / positions / masks
+(per-shard pool bytes exactly 1/N), FF weights along the hidden axis
+(the union buffer in N parts, each the union of one shard's F-range
+with its own live count), block tables / positions / masks
 replicated.  All host logic in this file is mesh-agnostic; sharded
 serving is token-identical to the single-device path, which stays the
 differential oracle (``tests/test_sharded_serving.py``).
 
 Self-speculative decoding (``spec_k > 0``, requires ``gcfg``): the
-GRIFFIN-compacted per-request FF weights already installed in each
-decode slot double as a weight-sharing draft model — the paper's
+GRIFFIN selections installed in the decode slots (the union FF) double
+as a weight-sharing draft model — the paper's
 flocking result says the 50%-FF model is nearly loss-free within a
 sequence, so its greedy continuations usually match the dense model's.
 One speculative tick per decode batch:
@@ -76,7 +88,7 @@ One speculative tick per decode batch:
      them.
   2. **draft + verify** — ONE fused device program
      (``decoder.draft_verify_paged``): a ``lax.scan`` runs the greedy
-     draft iterations *with the per-slot compacted weights* (argmax
+     draft iterations *with each slot's GRIFFIN neurons* (argmax
      feedback, draft-KV page writes, per-slot ``k_r`` masking all on
      device), then the same program re-scores the last committed token
      plus each slot's drafts in a ``[n_slots, spec_k + 1]`` dense
@@ -163,13 +175,18 @@ class PagedServer:
         # that only the attention kernel/oracle ever reads
         self.kv_dtype = kv_quant.resolve_kv_dtype(kv_dtype)
         self.cfg, self.params = cfg, params
-        # GRIFFIN selection/compaction always runs on host single-device
-        # arrays (the compacted tree is per-request host state); under a
-        # mesh ``self.params`` becomes the sharded copy, so keep the
-        # original for ``extract_ffn_tree``
+        # GRIFFIN selection and the union gather run on single-device
+        # arrays; under a mesh ``self.params`` becomes the sharded copy,
+        # so keep the original for ``extract_ffn_tree``
         self._host_params = params
         self.gcfg = gcfg if (gcfg is not None and cfg.griffin and cfg.has_ffn) \
             else None
+        if self.gcfg is not None and (not cfg.glu or cfg.use_bias):
+            raise ValueError(
+                f"{cfg.name}: the GRIFFIN union FF kernel covers bias-free "
+                f"GLU blocks (activation {cfg.activation!r}, "
+                f"use_bias={cfg.use_bias})"
+            )
         self.pcfg = PagedConfig(
             page_size=page_size, num_pages=num_pages,
             max_pages_per_request=-(-max_len // page_size),
@@ -220,11 +237,10 @@ class PagedServer:
                 "GRIFFIN per-layer expert budgets"
             )
         self._k_trees: Dict[float, Dict] = {}  # tier -> plan_k_tree
+        self._selectors: Dict[Optional[float], Any] = {}  # tier -> jit
         self._ffn_F = griffin_lib.ffn_widths(cfg) if self.gcfg is not None \
             else {}
-        # tick bucketing state: the widths signature the installed slot
-        # buffers were padded to, and which request each slot holds
-        self._bucket_sig = None
+        # which request each decode slot's selection belongs to
         self._slot_rid: Dict[int, int] = {}
         self.sched = Scheduler(self.pcfg, n_slots, prefill_chunk,
                                metrics=metrics, prefix_cache=prefix_cache)
@@ -233,7 +249,11 @@ class PagedServer:
             self.sched.spec_ctl = SpecController(spec_k)
         self.pools = decoder.init_paged_pools(cfg, num_pages, page_size,
                                               self.kv_dtype)
-        self.pruned_slots: Optional[Dict] = None  # per-slot compacted FF
+        # the slot table: each decode slot's selection, written only by
+        # _install_pruned; the union is built from it
+        self.pruned_slots: Optional[List[Dict]] = None
+        self._union: Optional[Dict] = None  # see _build_union
+        self._gather_union = jax.jit(griffin_lib.gather_union)
         self._next_rid = 0
         self._tick_attn_bytes = 0.0  # modeled KV read bytes, this tick
         # observability (DESIGN.md section 12): the tracer's hooks are
@@ -556,14 +576,14 @@ class PagedServer:
         self._count_attn_bytes([work.start], Lc, Wl, rows=1)
         collect = work.collect_stats and self.gcfg is not None
         # resume of a compacted request: generated-token positions must
-        # rebuild their KV with the same FF weights that decoded them, or
+        # rebuild their KV with the same FF neurons that decoded them, or
         # the restored cache (and all post-resume logits) diverge.  In
-        # vanilla GRIFFIN mode that is the request's compacted weights; in
+        # vanilla GRIFFIN mode that is the request's selection; in
         # speculative mode every committed token came from the *dense*
         # verifier, so the rebuild must stay dense too.
         use_pruned = work.use_pruned and not self.spec_k \
-            and req.pruned_host is not None  # tier 1.0 rebuilds dense
-        pruned = self._expand_b1(req.pruned_host) if use_pruned else None
+            and req.selection is not None  # tier 1.0 rebuilds dense
+        pruned = self._resume_union(req) if use_pruned else None
         tr = self.tracer
         with tr.span("prefill_dispatch"):
             logits, self.pools, stats = self._prefill(
@@ -586,48 +606,36 @@ class PagedServer:
         if work.is_last and not req.generated:
             first_token = int(np.argmax(self._fetch(logits)[0, Lc - 1]))
         self.sched.finish_prefill_chunk(work, first_token)
-        if work.is_last and req.state == DECODING and self.gcfg is not None:
-            if not req.compacted:
-                tier = req.tier if req.tier is not None else self.default_tier
-                if self.spec_k:
-                    # drafts always use the global budget: the dense
-                    # verifier commits every token, so tiering the draft
-                    # would change speed, never outputs
-                    tier = None
-                if tier == 1.0:
-                    # dense tier: no selection, no compacted buffers —
-                    # every decode of this request runs the unmodified
-                    # dense program (bit-exact to a no-gcfg server)
-                    req.pruned_host = None
-                    req.k_widths = None
-                else:
-                    ffn_tree = decoder.extract_ffn_tree(self._host_params,
-                                                        self.cfg)
-                    ks = None if tier is None else self._k_tree(tier)
-                    # per-layer budgets + shard-aware compaction behind
-                    # one entry point (griffin.select_and_compact);
-                    # ks=None is bit-identical to the legacy global
-                    # select_tree + compact_tree path.  The span times
-                    # the host work; its device ops land in the next wait
-                    with tr.span("compact", rid=req.rid, tier=tier):
-                        req.pruned_host, req.k_widths = \
-                            griffin_lib.select_and_compact(
-                                req.s_sq_acc, ffn_tree, self.gcfg, ks=ks)
-                    if self.flocking is not None and tier is None:
-                        # frozen selection + the statistic it was made
-                        # from, captured before the accumulator drops
-                        # (telemetry compares against the global budget,
-                        # so tiered requests are not tracked)
-                        sel = griffin_lib.select_tree(req.s_sq_acc,
-                                                      self.gcfg)
-                        self.flocking.on_select(
-                            req.rid, jax.tree.map(np.asarray, sel),
-                            jax.tree.map(np.asarray, req.s_sq_acc))
-                req.compacted = True
-                req.s_sq_acc = None
-            # slot install is deferred to the decode tick
-            # (_sync_pruned_slots): the buffer width every request pads
-            # to depends on which tiers share that tick
+        if work.is_last and req.state == DECODING and self.gcfg is not None \
+                and not req.compacted:
+            tier = req.tier if req.tier is not None else self.default_tier
+            if self.spec_k:
+                # drafts always use the global budget: the dense verifier
+                # commits every token, so tiering the draft would change
+                # speed, never outputs
+                tier = None
+            if tier == 1.0:
+                # dense tier: no selection — every decode of this request
+                # runs the unmodified dense program (bit-exact to a
+                # no-gcfg server)
+                req.selection = None
+            else:
+                # one jitted selection over the whole statistic tree,
+                # copied to the host once; the slot install and the
+                # union buffer wait for the decode tick
+                with tr.span("compact", rid=req.rid, tier=tier):
+                    req.selection = jax.device_get(
+                        self._selector(tier)(req.s_sq_acc))
+                if self.flocking is not None and tier is None:
+                    # frozen selection + the statistic it was made from,
+                    # captured before the accumulator drops (telemetry
+                    # compares against the global budget, so tiered
+                    # requests are not tracked)
+                    self.flocking.on_select(
+                        req.rid, _selection_tree(req.selection),
+                        jax.tree.map(np.asarray, req.s_sq_acc))
+            req.compacted = True
+            req.s_sq_acc = None
 
     def _decode_inputs(self, reqs: List[ScheduledRequest]):
         """Padded one-token decode inputs for the batch: each request's
@@ -649,27 +657,26 @@ class PagedServer:
     def _run_decode(self, reqs: List[ScheduledRequest]) -> None:
         B = self.n_slots
         toks, pos, mask, bts, W = self._decode_inputs(reqs)
-        # spec mode: the compacted weights are only the *draft* — a
+        # spec mode: the GRIFFIN neurons are only the *draft* — a
         # vanilla tick (pool-pressure fallback) must decode dense, or its
         # tokens and KV diverge from the dense stream the verifier commits
         use_griffin = self.gcfg is not None and not self.spec_k
         pruned = self._sync_pruned_slots(reqs) if use_griffin else None
-        dense_rows = [r for r in reqs if r.pruned_host is None] \
+        dense_rows = [r for r in reqs if r.selection is None] \
             if use_griffin else list(reqs)
-        pruned_rows = [r for r in reqs if r.pruned_host is not None] \
+        pruned_rows = [r for r in reqs if r.selection is not None] \
             if use_griffin else []
         if pruned is None:
             groups = [(None, list(reqs))]
         elif not dense_rows:
             groups = [(pruned, list(reqs))]
         else:
-            # mixed tick: compacted tiers share one padded-width pruned
-            # program; tier-1.0 rows run the unmodified *dense* program
-            # in a second dispatch.  Routing dense rows through
-            # identity-compacted per-slot weights instead is NOT
-            # bit-exact (the per-slot einsum contracts in a different
-            # order, ~1e-7 logit wobble), and tier 1.0 promises the
-            # dense path bit-exactly.  Each call masks the other
+            # mixed tick: compacted tiers share the union program;
+            # tier-1.0 rows run the unmodified *dense* program in a
+            # second dispatch.  Routing dense rows through an all-ones
+            # mask over a full union instead is NOT bit-exact (the kernel
+            # sums in blocks, ~1e-7 logit wobble), and tier 1.0 promises
+            # the dense path bit-exactly.  Each call masks the other
             # group's rows, so KV writes and committed tokens never mix.
             groups = [(pruned, pruned_rows), (None, dense_rows)]
         logits_by_slot = {}
@@ -681,7 +688,9 @@ class PagedServer:
                     gmask[r.slot] = mask[r.slot]
             self._count_attn_bytes([r.cache_len for r in group], 1, W,
                                    rows=B)
-            with self.tracer.span("decode_dispatch"):
+            share = {} if pr is None \
+                else {"ffn_live_share": self._union["share"]}
+            with self.tracer.span("decode_dispatch", **share):
                 logits, self.pools = self._decode(
                     self.params, self.pools, jnp.asarray(bts),
                     jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(gmask),
@@ -816,10 +825,9 @@ class PagedServer:
             draft[req.rid] = []
         bts_j = jnp.asarray(bts)
         num_steps = max(ks.values())
-        # in spec mode every request compacts at the global budget
-        # (tier=None), so the synced tree is always the uniform-width
-        # legacy layout
-        pruned_slots = self._sync_pruned_slots(reqs)
+        # in spec mode every request selects at the global budget
+        # (tier=None), so every row drafts through the union
+        pruned = self._sync_pruned_slots(reqs)
 
         # modeled attention traffic: at draft iteration ``i`` only the
         # slots still inside their own ``k_r`` are live — masked rows
@@ -835,7 +843,7 @@ class PagedServer:
         if self.spec_impl == "fused":
             # the whole round — k-step lax.scan draft chain (argmax
             # feedback, per-slot k_r masking, draft-KV page writes,
-            # compacted per-slot experts) AND the [B, K+1] dense verify
+            # the union FF) AND the [B, K+1] dense verify
             # — runs as ONE device program with ONE host sync
             # (decoder.draft_verify_paged), vs the legacy loop's
             # dispatch + sync per draft token plus a verify dispatch.
@@ -868,7 +876,7 @@ class PagedServer:
                     self.params, self.pools, jnp.asarray(btsd),
                     jnp.asarray(toks), jnp.asarray(pos),
                     jnp.asarray(kr_arr), jnp.asarray(live_arr),
-                    pruned_slots, n_scan,
+                    pruned, n_scan,
                 )
             # [slots, num_steps], [slots, K+1, V]
             dr, vlogits = self._fetch(dr, vlogits)
@@ -892,7 +900,7 @@ class PagedServer:
                     mask[s, 0] = i < ks[req.rid]
                 logits, self.pools = self._decode(
                     self.params, self.pools, bts_j, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(mask), pruned_slots,
+                    jnp.asarray(pos), jnp.asarray(mask), pruned,
                 )
                 logits = np.asarray(logits)
                 for req in reqs:
@@ -957,7 +965,7 @@ class PagedServer:
         for req in reversed(reqs):
             self.sched.rollback_draft(req)
 
-    # -- per-slot GRIFFIN weights ------------------------------------------
+    # -- GRIFFIN selections and the union FF --------------------------------
     def _k_tree(self, tier: float) -> Dict:
         """Per-layer expert budgets for a tier (cached — static per
         server: cfg, gcfg and profile never change after init)."""
@@ -966,116 +974,137 @@ class PagedServer:
                 self.cfg, self.gcfg, tier=tier, profile=self.profile)
         return self._k_trees[tier]
 
-    def _tick_widths(self, reqs: List[ScheduledRequest]) -> Dict[str, int]:
-        """Buffer width per FF layer for this tick's compacted batch.
+    def _selector(self, tier: Optional[float]):
+        """The jitted selection of every FF layer at a tier (cached: one
+        program per tier)."""
+        if tier not in self._selectors:
+            ks = None if tier is None else self._k_tree(tier)
+            self._selectors[tier] = jax.jit(functools.partial(
+                griffin_lib.select_per_layer, gcfg=self.gcfg, ks=ks))
+        return self._selectors[tier]
 
-        A single-width batch (all requests at one tier, or all legacy)
-        keeps its natural widths — today's exact program shapes.  Mixed
-        widths bucket to the next power of two above the tick's max
-        (rounded to a ``tp_shards`` multiple, capped at ``d_ff``), so
-        the distinct-program count stays ~log2(d_ff) instead of one per
-        tier combination; padding is bit-exact (zero ``w2`` rows)."""
-        sigs = {tuple(sorted(r.k_widths.items())) for r in reqs}
-        if len(sigs) == 1:
-            return dict(next(iter(sigs)))
-        sh = self.gcfg.tp_shards
-        out = {}
-        for path, (_, F) in self._ffn_F.items():
-            m = max(r.k_widths[path] for r in reqs)
-            w = 1 << (m - 1).bit_length()
-            if sh > 1:
-                w = -(-w // sh) * sh
-            out[path] = min(F, w)
-        return out
+    @property
+    def ffn_union(self) -> Optional[Dict]:
+        """The decode view of the union FF buffers (None before the first
+        GRIFFIN decode)."""
+        return None if self._union is None else self._union["dec"]
 
     def _sync_pruned_slots(self, reqs: List[ScheduledRequest]
                            ) -> Optional[Dict]:
-        """Bring ``self.pruned_slots`` up to date for this tick's batch
-        and return it (None when nobody needs compacted weights — an
-        all-dense-tier tick runs the plain dense program).
-
-        Buffers are installed lazily per (slot, rid): while the tick's
-        width signature is stable, only requests that newly entered (or
-        moved) a slot are written (``.at[slot].set`` — the legacy
-        incremental behavior); a width change rebuilds every live slot
-        at the new bucket."""
-        pruned_reqs = [r for r in reqs if r.pruned_host is not None]
-        if not pruned_reqs:
+        """Bring the union FF up to date for this tick's batch and return
+        its decode tree (None when nobody selected — an all-dense-tier
+        tick runs the plain dense program)."""
+        rows = [r for r in reqs if r.selection is not None]
+        if not rows:
             return None
-        widths = self._tick_widths(pruned_reqs)
-        sig = tuple(sorted(widths.items()))
-        rebuilt = sig != self._bucket_sig
-        if rebuilt:
-            self._bucket_sig = sig
-            self.pruned_slots = None
-            self._slot_rid = {}
-        new = [r for r in pruned_reqs if self._slot_rid.get(r.slot) != r.rid]
-        if not new:
-            return self.pruned_slots
-        shards = self.gcfg.tp_shards
-        # host work only: the writes' device ops land in the next wait
-        with self.tracer.span("slot_install", installed=len(new),
-                              rebuilt=rebuilt):
-            for r in new:
-                self._install_pruned(
-                    r.slot,
-                    griffin_lib.pad_pruned_tree(r.pruned_host, widths,
-                                                shards=shards),
-                )
-                self._slot_rid[r.slot] = r.rid
-        return self.pruned_slots
+        self._sync_union(rows)
+        return self._union["dec"]
 
-    def _expand_b1(self, pruned1: Dict) -> Dict:
-        """A request's compacted FF tree in the batch-of-1 slot layout
-        ``decode_step_paged`` expects (slot axis 0 for unrolled layers,
-        axis 1 for scan-stacked ones)."""
-        out: Dict[str, Any] = {}
-        for seg, layers in pruned1.items():
-            out[seg] = {}
-            for name, ffn in layers.items():
-                ax = 1 if name.startswith("pos") else 0
-                out[seg][name] = {k: jnp.expand_dims(v, ax)
-                                  for k, v in ffn.items()}
-        return out
+    def _resume_union(self, req: ScheduledRequest) -> Dict:
+        """The union tree a compacted request's resume prefill runs with:
+        the decode batch's slots plus one row for the request, which
+        holds no slot while it prefills."""
+        self._sync_union([r for r in self.sched.decoding
+                          if r.selection is not None], pre=req)
+        return self._union["pre"]
 
-    def _install_pruned(self, slot: int, pruned1: Dict) -> None:
-        """Write one request's compacted FF tree into its decode slot.
-
-        Slot buffers carry the slot axis at 0 for unrolled layers and at
-        1 (inside the scan-stacked layer axis) for scan segments, so the
-        decode ``lax.scan`` keeps scanning axis 0.
-        """
-
-        def leaf_axis(name: str) -> int:
-            return 1 if name.startswith("pos") else 0
-
-        if self.pruned_slots is None:
-            out: Dict[str, Any] = {}
-            for seg, layers in pruned1.items():
-                out[seg] = {}
-                for name, ffn in layers.items():
-                    ax = leaf_axis(name)
-                    out[seg][name] = {
-                        k: jnp.broadcast_to(
-                            jnp.expand_dims(v, ax),
-                            v.shape[:ax] + (self.n_slots,) + v.shape[ax:],
-                        )
-                        for k, v in ffn.items()
-                    }
-            self.pruned_slots = out
-            if self.tp is not None:
-                # commit the slot buffers mlp-sharded on the mesh so the
-                # compacted weights never replicate (the regression the
-                # divisible-k_ff rule exists to prevent)
-                self.pruned_slots = self.tp.shard_pruned(self.pruned_slots)
+    def _sync_union(self, rows: List[ScheduledRequest],
+                    pre: Optional[ScheduledRequest] = None) -> None:
+        """Install the selections of requests new to their slot, and
+        rebuild the union when its rows changed.  A departure needs no
+        rebuild: an empty slot's row is masked by the write mask."""
+        new = [r for r in rows if self._slot_rid.get(r.slot) != r.rid]
+        slots = frozenset(r.slot for r in rows)
+        if not new and self._union is not None \
+                and slots <= self._union["slots"] \
+                and (pre is None or self._union["pre_rid"] == pre.rid):
             return
-        for seg, layers in pruned1.items():
-            for name, ffn in layers.items():
-                buf = self.pruned_slots[seg][name]
-                for k, v in ffn.items():
-                    if leaf_axis(name):
-                        buf[k] = buf[k].at[:, slot].set(v)
-                    else:
-                        buf[k] = buf[k].at[slot].set(v)
+        # host work; the gather's device ops land in the next wait
+        with self.tracer.span("slot_install", installed=len(new)) as sp:
+            for r in new:
+                self._install_pruned(r.slot, r.selection)
+                self._slot_rid[r.slot] = r.rid
+            rebuilt, width = self._build_union(slots, pre)
+            if sp is not None:
+                sp.args.update(rebuilt=rebuilt, width=width)
+
+    def _build_union(self, slots, pre: Optional[ScheduledRequest]):
+        """The union of the slot table's selections for ``slots`` (and
+        ``pre``'s), per FF layer instance: masks and live counts for
+        every decode slot, and the buffer — gathered again only when the
+        union itself changed.  Returns (rebuilt, width: the widest
+        layer's |U|)."""
+        order = sorted(slots)
+        sels = [self.pruned_slots[s] for s in order]
+        if pre is not None:
+            sels.append(pre.selection)
+        groups = self.tp.n if self.tp is not None else 1
+        idx, dec, pre_mask, n_live, sizes = {}, {}, {}, {}, []
+        for path, (n, F) in self._ffn_F.items():
+            per = [griffin_lib.union_rows([sel[path][i] for sel in sels], F,
+                                          groups) for i in range(n)]
+            idx[path] = np.stack([p[0] for p in per])
+            masks = np.stack([p[1] for p in per])  # [n, rows, C]
+            dec[path] = np.zeros((n, self.n_slots, masks.shape[-1]),
+                                 np.float32)
+            dec[path][:, order] = masks[:, :len(order)]
+            pre_mask[path] = masks[:, len(order):]
+            n_live[path] = np.stack([p[2] for p in per])
+            sizes += [(p[3], F) for p in per]
+        u = self._union
+        rebuilt = u is None or any(not np.array_equal(idx[p], u["idx"][p])
+                                   for p in idx)
+        if rebuilt:
+            # free the old buffer before the new one is gathered
+            self._union = u = None
+            buf = self._gather_union(
+                decoder.extract_ffn_tree(self._host_params, self.cfg), idx)
+        else:
+            buf = u["buf"]
+        width = max(sz for sz, _ in sizes)
+        self._union = {
+            "slots": slots, "pre_rid": None if pre is None else pre.rid,
+            "idx": idx, "buf": buf,
+            "dec": self._union_tree(buf, dec, n_live),
+            "pre": None if pre is None
+            else self._union_tree(buf, pre_mask, n_live),
+            "share": float(np.mean([sz / F for sz, F in sizes])),
+            "width": width,
+        }
+        return rebuilt, width
+
+    def _union_tree(self, buf: Dict, masks: Dict, n_live: Dict) -> Dict:
+        """The step's ``pruned`` tree: each layer's buffers with its masks
+        and live counts (``decoder.decode_step_paged``)."""
+        tree: Dict[str, Any] = {}
+        for path in masks:
+            seg, name = path.split("/")
+            tree.setdefault(seg, {})[name] = dict(
+                buf[seg][name], mask=jnp.asarray(masks[path]),
+                n_live=jnp.asarray(n_live[path]))
         if self.tp is not None:
-            self.pruned_slots = self.tp.shard_pruned(self.pruned_slots)
+            # the parts of the union shard over the mesh: each device
+            # holds its own F-range's union and live counts
+            tree = self.tp.shard_pruned(tree)
+        return tree
+
+    def _install_pruned(self, slot: int, selection: Dict) -> None:
+        """Bind one request's selection to its decode slot.  The first
+        call fills every slot with it; the union is built from what this
+        writes."""
+        if self.pruned_slots is None:
+            self.pruned_slots = [selection] * self.n_slots
+        else:
+            self.pruned_slots[slot] = selection
+
+
+def _selection_tree(selection: Dict) -> Dict:
+    """A per-layer selection (``{path: [idx per instance]}``) in the
+    ``griffin.select_tree`` layout: [n, k] for scan leaves, [k] for
+    unrolled layers."""
+    out: Dict[str, Any] = {}
+    for path, sels in selection.items():
+        seg, name = path.split("/")
+        out.setdefault(seg, {})[name] = np.stack(sels) \
+            if name.startswith("pos") else sels[0]
+    return out

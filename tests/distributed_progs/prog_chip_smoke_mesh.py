@@ -4,7 +4,7 @@ at a tiny size.
 The tensor-parallel server over a ``model=4`` mesh against one device
 with the same balanced selection (``GriffinConfig(tp_shards=4)``):
 prefill logits within ``chip_smoke.LOGIT_TOL``, every request served in
-full with identical tokens, pools and slot buffers split over all four
+full with identical tokens, pools and FF union buffers split over all four
 devices.
 """
 import os

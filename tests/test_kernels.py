@@ -27,23 +27,34 @@ def _tol(dtype):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("B,D,F,BK,nsel", [
-    (1, 128, 512, 128, 2),
-    (4, 256, 1024, 128, 4),
-    (8, 128, 2048, 256, 3),
+@pytest.mark.parametrize("B,D,F,BK,L,n_live", [
+    (1, 128, 512, 128, 1, 2),
+    (4, 256, 1024, 128, 2, 4),
+    (8, 128, 2048, 256, 3, 3),
+    (4, 128, 512, 128, 2, 1),     # one live block
+    (2, 128, 1024, 128, 1, 8),    # every block live
 ])
 @pytest.mark.parametrize("act", ["swiglu", "geglu", "reglu"])
-def test_griffin_ffn_kernel(dtype, B, D, F, BK, nsel, act):
-    rng = np.random.default_rng(B * D + F)
+def test_griffin_ffn_kernel(dtype, B, D, F, BK, L, n_live, act):
+    """Each row sums only its own masked neurons of the live blocks of
+    its layer: rows of the buffer past the live count hold NaN and must
+    not reach y."""
+    rng = np.random.default_rng(B * D + F + n_live)
     x = jnp.asarray(rng.normal(size=(B, D)), dtype)
-    wg = jnp.asarray(rng.normal(size=(F, D)) * 0.05, dtype)
-    w1 = jnp.asarray(rng.normal(size=(F, D)) * 0.05, dtype)
-    w2 = jnp.asarray(rng.normal(size=(F, D)) * 0.05, dtype)
-    ids = jnp.asarray(
-        np.sort(rng.choice(F // BK, size=nsel, replace=False)), jnp.int32
-    )
-    y = ops.griffin_ffn_decode(x, wg, w1, w2, ids, block_size=BK, activation=act)
-    y_ref = ops.griffin_ffn_ref(x, wg, w1, w2, ids, BK, activation=act)
+
+    def weights():
+        w = rng.normal(size=(L, F, D)) * 0.05
+        w[:, n_live * BK:] = np.nan
+        return jnp.asarray(w, dtype)
+
+    wg, w1, w2 = weights(), weights(), weights()
+    mask = jnp.asarray(rng.random((B, F)) < 0.6, jnp.float32)
+    layer = L - 1
+    y = ops.griffin_ffn_decode(x, wg, w1, w2, mask, layer, n_live,
+                               block_size=BK, activation=act)
+    assert np.isfinite(np.asarray(y)).all()
+    y_ref = ops.griffin_ffn_ref(x, wg, w1, w2, mask, layer, n_live, BK,
+                                activation=act)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), **_tol(dtype))
 
 
@@ -85,11 +96,11 @@ def test_glu_ffn_kernel(dtype, S, D, F):
 
 
 def test_griffin_kernel_matches_model_ffn():
-    """Kernel path == the model's compact()+ffn_forward path when the
-    selection is block-aligned (the TPU mode's contract)."""
+    """Kernel path == the model's compact()+ffn_forward path: a buffer
+    holding the selected neurons' rows (padded past them), one mask row
+    per decode row."""
     from repro.configs.registry import get_config
-    from repro.core import GriffinConfig
-    from repro.core.selector import select_block_ids, select_blocks
+    from repro.core import GriffinConfig, select_experts
     from repro.models.layers import ffn as ffn_lib
 
     cfg = get_config("tinylm")
@@ -102,13 +113,16 @@ def test_griffin_kernel_matches_model_ffn():
     }
     x = jax.random.normal(jax.random.fold_in(key, 3), (2, 7, d))
     _, stats = ffn_lib.ffn_forward(p, x, cfg, collect_stats=True)
-    s = jnp.sqrt(jnp.sum(stats["s_sq"], 0))
-    idx = select_blocks(s, f // 2, bk)
-    bids = select_block_ids(s, f // 2, bk)
+    idx = select_experts(stats["s_sq"], GriffinConfig(sparsity=0.6))
+    k = int(idx.shape[0])
     y_model, _ = ffn_lib.ffn_forward(ffn_lib.compact_ffn_params(p, idx), x, cfg)
+    buf_idx = jnp.concatenate([idx, jnp.full((f - k,), idx[-1])])
+    rows = lambda w: jnp.take(w, buf_idx, axis=0)[None]
+    mask = jnp.broadcast_to(jnp.arange(f) < k, (2, f))
     xq = x[:, -1]  # decode: one token
     y_kernel = ops.griffin_ffn_decode(
-        xq, p["wg"].T, p["w1"].T, p["w2"], bids, block_size=bk
+        xq, rows(p["wg"].T), rows(p["w1"].T), rows(p["w2"]), mask, 0,
+        -(-k // bk), block_size=bk,
     )
     np.testing.assert_allclose(
         np.asarray(y_kernel), np.asarray(y_model[:, -1]), rtol=1e-4, atol=1e-4

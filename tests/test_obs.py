@@ -437,9 +437,11 @@ def _inside(events, parent):
 def test_tick_span_tree(tiny, tier):
     """A traced drain splits each ``decode`` span into the dispatch, the
     wait for the device and the logits copy; a compacted tier records
-    one ``compact`` per request and a ``slot_install`` whose
-    ``installed`` args count every slot written, and the dense tier
-    records neither.  No pool counter and no straggler-host gauge."""
+    one ``compact`` per request, a ``slot_install`` whose ``installed``
+    args count every slot written and whose ``width`` is the widest
+    layer's union, and a ``ffn_live_share`` on every decode dispatch;
+    the dense tier records none of them.  No pool counter and no
+    straggler-host gauge."""
     cfg, params = tiny
     tr = Tracer()
     srv = PagedServer(cfg, params,
@@ -450,9 +452,9 @@ def test_tick_span_tree(tiny, tier):
     installs = []
     orig = srv._install_pruned
 
-    def counting(slot, pruned1):
+    def counting(slot, selection):
         installs.append(slot)
-        orig(slot, pruned1)
+        orig(slot, selection)
 
     srv._install_pruned = counting
     prompts = _prompts(cfg, 4)
@@ -472,6 +474,8 @@ def test_tick_span_tree(tiny, tier):
     assert len(first) == len(prompts)
     compacts = [e for e in x if e["name"] == "compact"]
     slot_installs = [e for e in x if e["name"] == "slot_install"]
+    shares = [e["args"].get("ffn_live_share") for e in x
+              if e["name"] == "decode_dispatch"]
     if tier < 1.0:
         assert sorted(e["args"]["rid"] for e in compacts) == [0, 1, 2, 3]
         assert all(e["args"]["tier"] == tier for e in compacts)
@@ -479,8 +483,16 @@ def test_tick_span_tree(tiny, tier):
         assert sum(e["args"]["installed"] for e in slot_installs) \
             == len(installs)
         assert all(e["args"]["parent"] == "decode" for e in slot_installs)
+        # a union holds at least one request's k and at most every
+        # neuron of the layer
+        k = srv.gcfg.k_of(cfg.d_ff)
+        assert all(k <= e["args"]["width"] <= cfg.d_ff
+                   for e in slot_installs)
+        assert shares and all(
+            k / cfg.d_ff <= v <= 1.0 for v in shares), shares
     else:
         assert compacts == [] and slot_installs == [] and installs == []
+        assert shares and all(v is None for v in shares)
     assert not any(e["ph"] == "C" for e in tr.events)
     assert "serving_straggler_hosts" not in srv.metrics.prometheus_text()
     assert validate_chrome_trace(chrome_trace(tr)) == []

@@ -260,9 +260,9 @@ def test_cached_s_sq_resume_bitexact(tiny):
 
 
 def test_server_warm_selection_identical_to_cold(tiny):
-    """End-to-end: a prefix-hit request must compact *exactly* the
-    weights a cold run selects (bit-equal pruned trees), and emit the
-    same tokens."""
+    """End-to-end: a prefix-hit request must select *exactly* the
+    neurons a cold run selects (equal index arrays), and emit the same
+    tokens."""
     cfg, params = tiny
     gcfg = GriffinConfig(sparsity=0.5, per_shard_topk=False)
     rng = np.random.default_rng(21)
@@ -276,13 +276,13 @@ def test_server_warm_selection_identical_to_cold(tiny):
         srv.submit(prompt, 6, rid=0)  # donor (identical in both modes)
         srv.drain()
         srv.submit(prompt.copy(), 6, rid=1)  # clone
-        # the clone's compacted tree, read while it decodes (a finished
+        # the clone's selection, read while it decodes (a finished
         # request releases it)
         while srv.step():
             live = [r for r in srv.sched.decoding
                     if r.rid == 1 and r.compacted]
             if live and mode not in pruned:
-                pruned[mode] = live[0].pruned_host
+                pruned[mode] = live[0].selection
         outs[mode] = srv.drain()
         if pc:
             assert srv.metrics.prefix_hits > 0

@@ -439,8 +439,8 @@ def test_server_preemption_preserves_outputs(tiny):
 def test_server_mid_decode_preemption_preserves_outputs(tiny):
     """Evicting a request that already compacted and decoded several
     tokens must reproduce the uninterrupted run exactly: the resume
-    prefill rebuilds generated-token KV with the request's *compacted*
-    FF weights (full weights there would shift every post-resume
+    prefill rebuilds generated-token KV with the request's *selected*
+    FF neurons (full weights there would shift every post-resume
     logit)."""
     from repro.serving.engine import GenerationEngine
 
@@ -462,25 +462,26 @@ def test_server_mid_decode_preemption_preserves_outputs(tiny):
     srv = PagedServer(cfg, params, gcfg=gcfg, page_size=8, num_pages=8,
                       n_slots=2, prefill_chunk=16, max_len=40)
     pruned_resumes = []
-    orig_expand = srv._expand_b1
-    srv._expand_b1 = lambda t: (pruned_resumes.append(1), orig_expand(t))[1]
+    orig_resume = srv._resume_union
+    srv._resume_union = lambda r: (pruned_resumes.append(1),
+                                   orig_resume(r))[1]
     for i, p in enumerate(prompts):
         srv.submit(p, max_new, rid=i)
     results = srv.drain()
     assert results == expected
     assert srv.metrics.summary()["preemptions"] >= 1
     # the victim really was compacted + mid-decode: its resume re-prefill
-    # must have gone through the compacted-weight path
+    # must have gone through the union FF path
     assert pruned_resumes
 
 
 def test_resume_prefill_rebuilds_decode_kv_exactly(tiny):
     """The KV a resume prefill rebuilds for generated-token positions
-    must match what live decode wrote — which requires the compacted FF
-    weights at those positions (this tiny model's greedy tokens collapse,
+    must match what live decode wrote — which requires the selected FF
+    neurons at those positions (this tiny model's greedy tokens collapse,
     so the check must be at logits level, where the full-weight rebuild
     measurably diverges)."""
-    from repro.core import compact_tree, select_tree
+    from repro.core import griffin as griffin_lib
 
     cfg, params = tiny
     gcfg = GriffinConfig(sparsity=0.5, per_shard_topk=False)
@@ -493,32 +494,39 @@ def test_resume_prefill_rebuilds_decode_kv_exactly(tiny):
 
     bt = np.arange(W, dtype=np.int32)[None]
 
-    # live run: full prefill -> compact -> G pruned decode steps
+    # live run: full prefill -> select -> G decode steps over the union
+    # buffer of this one request
     logits, pools, stats = decoder.decode_step_paged(
         params, cfg, fresh_pools(), jnp.asarray(bt), prompt,
         jnp.array([0], np.int32), collect_stats=True)
-    sel = select_tree(decoder.prune_stats_tree(stats, cfg), gcfg)
-    pruned1 = compact_tree(decoder.extract_ffn_tree(params, cfg), sel)
+    sel = jax.device_get(griffin_lib.select_per_layer(
+        decoder.prune_stats_tree(stats, cfg), gcfg))
+    idx, masks, n_live = {}, {}, {}
+    for path, per in sel.items():
+        F = griffin_lib.ffn_widths(cfg)[path][1]
+        rows = [griffin_lib.union_rows([s], F) for s in per]
+        idx[path] = np.stack([r[0] for r in rows])
+        masks[path] = np.stack([r[1] for r in rows])  # [n, 1, C]
+        n_live[path] = np.stack([r[2] for r in rows])
+    buf = griffin_lib.gather_union(decoder.extract_ffn_tree(params, cfg),
+                                   idx)
+    union = {seg: {name: dict(leaf, mask=jnp.asarray(masks[f"{seg}/{name}"]),
+                              n_live=jnp.asarray(n_live[f"{seg}/{name}"]))
+                   for name, leaf in layers.items()}
+             for seg, layers in buf.items()}
 
-    def expand_b1(tree):
-        return {seg: {name: {k: jnp.expand_dims(v,
-                                                1 if name.startswith("pos") else 0)
-                             for k, v in ffn.items()}
-                      for name, ffn in layers.items()}
-                for seg, layers in tree.items()}
-
-    pruned_b1 = expand_b1(pruned1)
     gen = [int(np.argmax(np.asarray(logits)[0, S - 1]))]
     for t in range(G + 1):
         logits, pools, _ = decoder.decode_step_paged(
             params, cfg, pools, jnp.asarray(bt),
             jnp.asarray([[gen[-1]]], np.int32),
-            jnp.array([S + t], np.int32), pruned=pruned_b1)
+            jnp.array([S + t], np.int32), pruned=union)
         gen.append(int(np.argmax(np.asarray(logits)[0, 0])))
     live_logits = np.asarray(logits)  # step consuming gen[G] at pos S+G
 
-    # resume rebuild: prompt with full weights, generated with compacted;
-    # then replay the last live step and compare its logits
+    # resume rebuild: prompt with full weights, generated with the
+    # request's neurons; then replay the last live step and compare its
+    # logits
     def rebuild(use_pruned_for_generated):
         pools_r = fresh_pools()
         _, pools_r, _ = decoder.decode_step_paged(
@@ -528,17 +536,99 @@ def test_resume_prefill_rebuilds_decode_kv_exactly(tiny):
         _, pools_r, _ = decoder.decode_step_paged(
             params, cfg, pools_r, jnp.asarray(bt), gen_toks,
             jnp.array([S], np.int32),
-            pruned=pruned_b1 if use_pruned_for_generated else None)
+            pruned=union if use_pruned_for_generated else None)
         logits_r, _, _ = decoder.decode_step_paged(
             params, cfg, pools_r, jnp.asarray(bt),
             jnp.asarray([[gen[G]]], np.int32),
-            jnp.array([S + G], np.int32), pruned=pruned_b1)
+            jnp.array([S + G], np.int32), pruned=union)
         return np.asarray(logits_r)
 
     good = rebuild(use_pruned_for_generated=True)
     np.testing.assert_allclose(good, live_logits, rtol=0, atol=1e-6)
     bad = rebuild(use_pruned_for_generated=False)
     assert float(np.max(np.abs(bad - live_logits))) > 1e-4  # discriminates
+
+
+def _decode_logits(srv):
+    """Record every decode row's logits by (rid, position) as the
+    server's jitted decode returns them, and each call's row count."""
+    seen, rows = {}, []
+    step = srv._decode
+
+    def wrapped(params, pools, bts, toks, pos, mask, pruned):
+        logits, pools = step(params, pools, bts, toks, pos, mask, pruned)
+        host, pos, mask = np.asarray(logits), np.asarray(pos), np.asarray(mask)
+        rows.append(int(mask.sum()))
+        for r in srv.sched.decoding:
+            if mask[r.slot, 0]:
+                seen[(r.rid, int(pos[r.slot]))] = host[r.slot, 0]
+        return logits, pools
+
+    srv._decode = wrapped
+    return seen, rows
+
+
+def test_union_decode_matches_each_request_alone(tiny):
+    """Four requests with their own selections decode together through
+    one union buffer, each with its own mask: every token equals the
+    request's solo run and every logit lies within 1e-5 of it."""
+    from repro.obs.trace import Tracer
+
+    cfg, params = tiny
+    gcfg = GriffinConfig(sparsity=0.5, per_shard_topk=False)
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (12, 9, 14, 11)]
+    kw = dict(page_size=8, num_pages=48, n_slots=4, prefill_chunk=16,
+              max_len=64)
+
+    tr = Tracer()
+    srv = PagedServer(cfg, params, gcfg=gcfg, tracer=tr, **kw)
+    together, rows = _decode_logits(srv)
+    for i, p in enumerate(prompts):
+        srv.submit(p, 8, rid=i)
+    out = srv.drain()
+    k = gcfg.k_of(cfg.d_ff)
+    widths = [e["args"]["width"] for e in tr.events
+              if e["name"] == "slot_install"]
+    assert max(widths) > k  # the selections differ: a real union
+    assert max(rows) == 4  # all four shared a tick
+
+    for i, p in enumerate(prompts):
+        solo_srv = PagedServer(cfg, params, gcfg=gcfg, **kw)
+        alone, _ = _decode_logits(solo_srv)
+        solo_srv.submit(p, 8, rid=i)
+        assert solo_srv.drain()[i] == out[i], f"rid={i} tokens diverged"
+        assert alone and set(alone) <= set(together)
+        for key, v in alone.items():
+            np.testing.assert_allclose(together[key], v, rtol=0, atol=1e-5)
+
+
+def test_union_of_one_slot_is_its_selection(tiny):
+    """With one live slot the union is the request's own selection: k
+    neurons per layer, ceil(k / block) live blocks, a mask of k ones."""
+    from repro.core import griffin as griffin_lib
+
+    cfg, params = tiny
+    gcfg = GriffinConfig(sparsity=0.5, per_shard_topk=False)
+    srv = PagedServer(cfg, params, gcfg=gcfg, page_size=8, num_pages=32,
+                      n_slots=4, prefill_chunk=16, max_len=64)
+    prompt = np.random.default_rng(32).integers(0, cfg.vocab_size, 20)
+    srv.submit(prompt.astype(np.int32), 4, rid=0)
+    while not srv.sched.decoding:
+        srv.step()
+    srv.step()
+    k = gcfg.k_of(cfg.d_ff)
+    u = srv._union
+    assert u["width"] == k and u["share"] == k / cfg.d_ff
+    slot = srv.sched.decoding[0].slot
+    for layers in srv.ffn_union.values():
+        for leaf in layers.values():
+            n_live = np.asarray(leaf["n_live"])
+            assert (n_live == -(-k // griffin_lib.UNION_BLOCK)).all()
+            mask = np.asarray(leaf["mask"])
+            assert (mask[:, slot].sum(-1) == k).all()
+    srv.drain()
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,8 @@ pattern as ``test_distributed.py``: the device-count flag must be set
 before jax initializes and must never leak into the main test process.
 
 The in-process tests below cover the host-side TP machinery that needs
-no devices: the balanced divisible-``k_ff`` selection and the per-slot
-compacted-FF PartitionSpec layout.
+no devices: the balanced divisible-``k_ff`` selection and the union-FF
+PartitionSpec layout.
 """
 import os
 import subprocess
@@ -59,9 +59,9 @@ def test_balanced_selection_pads_k_to_shards():
 
 
 def test_pruned_pspecs_shard_compacted_ffn():
-    """The per-slot compacted FF tree shards along its expert axis —
-    w1/wg/b1 on the last dim, w2 on the second-to-last — and rejects a
-    width the mesh axis cannot divide (instead of silently
+    """The union FF tree shards along its neuron axis — buffers on the
+    second dim, masks on the last, live counts one per shard — and
+    rejects a capacity the mesh axis cannot divide (instead of silently
     replicating)."""
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
@@ -72,21 +72,24 @@ def test_pruned_pspecs_shard_compacted_ffn():
     # AbstractMesh: spec resolution needs axis names/sizes, no devices
     mesh = AbstractMesh((2,), ("model",))
     fac = tp_lib.PagedTP(cfg, mesh)
-    D, k, L, B = cfg.d_model, 256, 4, 3
+    D, C, L, B = cfg.d_model, 512, 4, 3
     z = np.zeros
     pruned = {
         "seg0": {
             "pos0": {
-                "w1": z((L, B, D, k), np.float32),
-                "wg": z((L, B, D, k), np.float32),
-                "w2": z((L, B, k, D), np.float32),
+                "w1": z((L, C, D), np.float32),
+                "wg": z((L, C, D), np.float32),
+                "w2": z((L, C, D), np.float32),
+                "mask": z((L, B, C), np.float32),
+                "n_live": z((L, 2), np.int32),
             }
         }
     }
-    specs = fac.pruned_pspecs(pruned)
-    assert specs["seg0"]["pos0"]["w1"] == P(None, None, None, "model")
-    assert specs["seg0"]["pos0"]["wg"] == P(None, None, None, "model")
-    assert specs["seg0"]["pos0"]["w2"] == P(None, None, "model")
+    specs = fac.pruned_pspecs(pruned)["seg0"]["pos0"]
+    for k in ("w1", "wg", "w2"):
+        assert specs[k] == P(None, "model")
+    assert specs["mask"] == P(None, None, "model")
+    assert specs["n_live"] == P(None, "model")
 
 
 def test_pruned_pspecs_reject_indivisible_k():
@@ -99,7 +102,7 @@ def test_pruned_pspecs_reject_indivisible_k():
     mesh = AbstractMesh((2,), ("model",))
     fac = tp_lib.PagedTP(cfg, mesh)
     pruned = {"seg0": {"pos0": {
-        "w1": np.zeros((4, 3, cfg.d_model, 255), np.float32),
+        "w1": np.zeros((4, 255, cfg.d_model), np.float32),
     }}}
     with pytest.raises(ValueError, match="tp_shards"):
         fac.pruned_pspecs(pruned)

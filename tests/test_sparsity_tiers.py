@@ -4,8 +4,8 @@ Four layers of coverage, mirroring the refactor's guarantees:
 
 * unit — ``resolve_tier``/``tier_k`` budgets, ``SparsityProfile``
   round-trip + validation, ``plan_k_tree`` per-layer widths,
-* selector/compaction grid — ``pad_selection``/``pad_compacted``
-  padding and the ``compaction_shards`` predicate across
+* selector/compaction grid — ``pad_selection`` padding and the
+  ``compaction_shards`` predicate across
   ``mode ∈ {topk, sampling, blocks}`` × ``tp_shards`` × per-layer ``k``,
 * end-to-end — tier=1.0 ≡ dense oracle, tier=0.5 uniform ≡ legacy
   global sparsity=0.5 (through preemption and spec_k ∈ {0, 4}), and
@@ -39,7 +39,6 @@ from repro.core import (
 )
 from repro.core import selector as selector_lib
 from repro.models import decoder
-from repro.models.layers import ffn as ffn_lib
 from repro.serving.server import PagedServer
 from repro.serving.slo import SLOClass
 
@@ -185,44 +184,6 @@ def test_compaction_shards_predicate():
     assert griffin_lib.compaction_shards(g(tp_shards=4), 16, 66) == 1  # F % 4
 
 
-@pytest.mark.parametrize("shards", [1, 2])
-def test_pad_compacted_dead_rows_are_inert(shards):
-    """Padding a compacted FF to a wider bucket must not change its
-    output: dead w2 rows are zeroed, so garbage columns cannot leak."""
-    rng = np.random.default_rng(1)
-    D, F, k, k_pad = 8, 32, 8, 16
-    ffn = {
-        "w1": jnp.asarray(rng.standard_normal((D, F)), jnp.float32),
-        "wg": jnp.asarray(rng.standard_normal((D, F)), jnp.float32),
-        "b1": jnp.asarray(rng.standard_normal((F,)), jnp.float32),
-        "bg": jnp.asarray(rng.standard_normal((F,)), jnp.float32),
-        "w2": jnp.asarray(rng.standard_normal((F, D)), jnp.float32),
-        "b2": jnp.asarray(rng.standard_normal((D,)), jnp.float32),
-    }
-    s_sq = jnp.asarray(rng.random((3, F)), jnp.float32)
-    gcfg = GriffinConfig(sparsity=0.75, tp_shards=shards)
-    idx = select_experts(s_sq, gcfg, k=k)
-    idx_p, keep = selector_lib.pad_selection(idx, k, F, shards=shards)
-    small = griffin_lib.compact(ffn, idx_p, shards=shards)
-    small = griffin_lib._mask_dead_rows(small, keep)
-
-    wide = ffn_lib.pad_compacted(small, k_pad, shards=shards)
-    assert wide["w2"].shape == (k_pad, D)
-
-    x = jnp.asarray(rng.standard_normal((5, D)), jnp.float32)
-
-    def ff(p):
-        h = jax.nn.silu(x @ p["wg"] + p["bg"]) * (x @ p["w1"] + p["b1"])
-        return h @ p["w2"] + p["b2"]
-
-    assert jnp.array_equal(ff(small), ff(wide)), "padding changed the math"
-    with pytest.raises(ValueError):
-        ffn_lib.pad_compacted(small, k - 4)  # narrowing is not padding
-    if shards > 1:
-        with pytest.raises(ValueError):
-            ffn_lib.pad_compacted(small, k_pad + 1, shards=shards)
-
-
 def test_select_and_compact_per_layer_ks(tiny):
     """The single entry point honors per-instance budgets: scan leaves
     pad to the widest instance, narrower instances carry dead rows."""
@@ -330,8 +291,9 @@ def test_default_tier_applies_to_untiered_requests(tiny):
 
 
 def test_mixed_tier_batch_matches_single_tier_runs(tiny):
-    """Each stream of a mixed-tier batch (one tick, split dispatch,
-    bucketed widths) is identical to running that request alone."""
+    """Each stream of a mixed-tier batch (one tick, split dispatch, one
+    union of both compacted tiers) is identical to running that request
+    alone."""
     cfg, params = tiny
     prompts = _prompts(cfg, n=3, seed=9)
     gcfg = GriffinConfig(sparsity=0.5)
@@ -343,8 +305,8 @@ def test_mixed_tier_batch_matches_single_tier_runs(tiny):
 
 
 def test_tiered_server_with_profile_runs_and_tracks_widths(tiny):
-    """A non-flat profile changes per-layer widths but still drains; the
-    request records its per-layer k map for bucketing."""
+    """A non-flat profile changes per-layer widths but still drains; each
+    request's selection keeps every instance's own budget."""
     cfg, params = tiny
     widths = griffin_lib.ffn_widths(cfg)
     path0 = next(iter(widths))
@@ -361,6 +323,11 @@ def test_tiered_server_with_profile_runs_and_tracks_widths(tiny):
     prompts = _prompts(cfg, n=2)
     for i, p in enumerate(prompts):
         srv.submit(p, 6, rid=i)
+    widths = []
+    while srv.step():
+        widths += [[len(s) for s in r.selection[path0]]
+                   for r in srv.sched.decoding]
+    assert widths and all(w == list(ks) for w in widths)
     out = srv.drain()
     assert all(len(v) == 6 for v in out.values())
 

@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
+from repro.kernels.griffin_ffn import griffin_ffn
 from repro.kernels.paged_attn import paged_attn
 from repro.kernels.paged_gather import paged_gather
 
@@ -106,4 +107,23 @@ def test_paged_kv_gather_compiles(one_chip):
         return paged_gather(pool, bt, interpret=False)
 
     compiled = jax.jit(gather).lower(pool, bt).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [BATCH, 32])  # decode slots; a prefill chunk
+def test_griffin_ffn_compiles(one_chip, rows):
+    """The union FF kernel at yi-9b widths: an 8-layer stacked bf16
+    buffer of capacity d_ff, 128-neuron blocks, a per-row mask."""
+    cfg = get_config("yi-9b")
+    L, C, D = 8, cfg.d_ff, cfg.d_model
+    w = _sds((L, C, D), cfg.dtype, one_chip)
+    args = (_sds((rows, D), cfg.dtype, one_chip), w, w, w,
+            _sds((rows, C), jnp.float32, one_chip),
+            _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+
+    def ffn(x, wg, w1, w2, mask, layer, n_live):
+        return griffin_ffn(x, wg, w1, w2, mask, layer, n_live,
+                           block_size=128, interpret=False)
+
+    compiled = jax.jit(ffn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
