@@ -63,17 +63,15 @@ def kept_overlap(cell, w, sample):
     of kept neurons that two prompts have in common, per tier."""
     import jax.numpy as jnp
 
-    from bench import flops, reference
-
-    T = cell.config["serving"]["max_len"]
+    arch, T = cell.arch, cell.config["serving"]["max_len"]
     out = {}
     for tier in sorted({r.tier for r in sample if r.tier < 1.0}):
-        k = flops.tier_k(cell.spec, tier)
+        k = arch.keep(cell.spec, tier)
         masks = []
         for r in (r for r in sample if r.tier == tier):
             seq = np.zeros((T,), np.int32)
             seq[: len(r.prompt)] = r.prompt
-            masks.append(np.asarray(reference.kept(
+            masks.append(np.asarray(arch.kept(
                 cell.spec, w, jnp.asarray(seq), jnp.int32(len(r.prompt)),
                 k)))
         pairs = [float(((a & b).sum(-1) / k).mean())

@@ -7,9 +7,10 @@ are stamped on this clock when it returns (when a streaming user would
 see them).  Latencies run from the time a request was due.
 
 Everything the benchmark measures with lives under ``bench/``: the
-generator, the weights, the metric arithmetic, the trace reduction and
-the reference.  From the program it takes the server, the decoder's
-parameter layout and the config registry.
+generator, the metric arithmetic, the trace reduction, and in the
+configuration's yardstick (``cell.arch``, ``bench/arch/``) the weights,
+the reference and the counts.  From the program it takes the server,
+the decoder's parameter layout and the config registry.
 """
 from __future__ import annotations
 
@@ -22,7 +23,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import flops as flops_lib
 from bench.spec import ROOT, Cell, metric_module
 from bench.traffic import generate
 
@@ -125,23 +125,30 @@ class RunData:
 
 # -- set-up -----------------------------------------------------------------
 
+def seed_key(seed: int):
+    """A raw threefry key from a seed of any size (64-bit seeds too)."""
+    import jax.numpy as jnp
+
+    state = np.random.SeedSequence(int(seed) & (2**128 - 1)).generate_state(2)
+    return jnp.asarray(state, dtype=jnp.uint32)
+
+
 def build_server(cell: Cell, seed: int, tracer=None):
-    """Weights from the seed (one jitted call), then the server as the
-    configuration deploys it; the attention backend, page size and
-    prefill chunk stay at the server's defaults."""
+    """Weights from the seed (the yardstick's one jitted call), then the
+    server as the configuration deploys it; the attention backend, page
+    size and prefill chunk stay at the server's defaults."""
     import inspect
 
     import jax
 
-    from bench import weights as weights_lib
-    from bench.spec import program_config
     from repro.core.griffin import GriffinConfig
     from repro.serving.server import PagedServer
 
-    cfg = program_config(cell.config)
-    w = weights_lib.make(cell.spec, weights_lib.seed_key(seed))
+    arch = cell.arch
+    cfg = arch.program_config(cell.config)
+    w = arch.make(cell.spec, seed_key(seed))
     jax.block_until_ready(w)
-    params = weights_lib.program_params(w, cfg)
+    params = arch.program_params(w, cfg)
     sv = cell.config["serving"]
     page = inspect.signature(PagedServer.__init__).parameters[
         "page_size"].default
@@ -360,16 +367,14 @@ def gap_readings(cell: Cell, w, sample: List[Record],
     with the token and request counts."""
     import jax.numpy as jnp
 
-    from bench import reference
-
-    s, T = cell.spec, cell.config["serving"]["max_len"]
+    arch, s, T = cell.arch, cell.spec, cell.config["serving"]["max_len"]
     per: Dict[float, Dict[str, list]] = {}
     for r in sample:
         seq = np.zeros((T,), np.int32)
         toks = np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)])
         seq[: len(toks)] = toks
-        k = None if r.tier >= 1.0 else flops_lib.tier_k(s, r.tier)
-        served, ctl = reference.gaps(
+        k = None if r.tier >= 1.0 else arch.keep(s, r.tier)
+        served, ctl = arch.gaps(
             s, w, jnp.asarray(seq), jnp.int32(len(r.prompt)),
             jnp.int32(len(toks)), k=k, control=control)
         on = slice(len(r.prompt) - 1, len(toks) - 1)

@@ -35,9 +35,10 @@ def tiny_cell(tier: float = 0.5) -> spec_lib.Cell:
     t = spec_lib.load_json(HERE / "tiny-chat.json")
     t["tiers"] = {str(tier): 1.0}
     b = spec_lib.benchmark()
+    arch = spec_lib.yardstick(c)
     return spec_lib.Cell(
-        name="tiny", chips=1, config=c, traffic=t,
-        spec=spec_lib.ModelSpec.from_file(c),
+        name="tiny", chips=1, config=c, traffic=t, arch=arch,
+        spec=arch.ModelSpec.from_file(c),
         per_layer=[m for m in b["per_layer"]
                    if m["source"] != "device_trace"],
         end_to_end=b["end_to_end"])
@@ -80,13 +81,11 @@ def test_wrong_selection_is_not_correct(fault):
 def test_control_reads_above_the_limit():
     """Runs the reference with int8 weights where the program was, on
     the same requests, and reads the gap of its first-ranked tokens."""
-    from bench import weights as weights_lib
-
     cell = tiny_cell(0.5)
     run, res = harness.run_cell(cell, 2**33 + 7, SECONDS, False,
                                 time.perf_counter())
     assert res["correct"]
-    w = weights_lib.make(cell.spec, weights_lib.seed_key(2**33 + 7))
+    w = cell.arch.make(cell.spec, harness.seed_key(2**33 + 7))
     sample = harness.check_sample(run, 10**6, 10**6)
     readings = harness.gap_readings(cell, w, sample, control=True)[0.5]
     for name, limits in cell.config["check"]["limits"].items():
